@@ -22,6 +22,9 @@ def main(argv=None):
                    help="comma-separated subset: fig8,fig9,...,kernels")
     args = p.parse_args(argv)
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from . import (chaos_bench, fig8_datasets, fig9_skew,
                    fig10_reduce_tasks, fig11_sorted, fig12_map_output,
                    fig13_scaling, fig_sn_window, kernel_bench,

@@ -9,12 +9,15 @@ device and re-admit it after its revive.
 """
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import compute_bdm, plan_block_split
 from repro.er import ERService, ServiceConfig, make_products
 from repro.er.blocking import exponential_block_ids
 from repro.er.compiler import (FaultEvent, FaultInjector, FaultScript,
                                execute, execute_supervised, lower,
                                plan_to_job)
+
+use_compile_cache()
 
 N_DEV, THRESH = 8, 0.4
 

@@ -12,7 +12,10 @@ blocking strategies use.
 """
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.er import ERConfig, make_products, run_er
+
+use_compile_cache()
 
 ds = make_products(8_000, seed=0)
 

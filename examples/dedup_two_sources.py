@@ -6,6 +6,7 @@ with PairRange balancing over the rectangular |Φ_R|×|Φ_S| enumeration.
 """
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import compute_bdm
 from repro.core.two_source import (TwoSourceBDM, plan_block_split_2src,
                                    plan_pair_range_2src, pairs_of_range_2src)
@@ -13,6 +14,8 @@ from repro.er import make_products
 from repro.er.blocking import prefix_block_ids
 from repro.er.encode import encode_titles, ngram_features
 from repro.er.similarity import edit_similarity
+
+use_compile_cache()
 
 R_SIZE, S_SIZE, R_TASKS = 3_000, 2_000, 12
 

@@ -7,7 +7,10 @@ the two-source R × S plans of Appendix I.
 """
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.er import ERService, ServiceConfig, compile_counter, make_products
+
+use_compile_cache()
 
 CORPUS_N, BATCHES = 3_000, 8
 

@@ -8,7 +8,10 @@ different load balance.
 """
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.er import ERConfig, make_products, run_er
+
+use_compile_cache()
 
 ds = make_products(5_000, seed=0)
 print(f"dataset: {ds.n} product titles, {len(ds.true_pairs)} injected duplicates")

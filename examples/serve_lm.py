@@ -10,12 +10,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import ARCHS, get_config, reduced
 from repro.models import get_model
 from repro.serve import generate
 
 
 def main():
+    use_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="smollm-360m")
     p.add_argument("--batch", type=int, default=4)

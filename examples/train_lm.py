@@ -9,9 +9,11 @@ config: smollm trunk at 12 layers × d=512.
 """
 import sys
 
+from repro.compile_cache import use_compile_cache
 from repro.launch.train import main
 
 if __name__ == "__main__":
+    use_compile_cache()
     argv = sys.argv[1:]
     if not any(a.startswith("--arch") for a in argv):
         argv = ["--arch", "smollm-360m", "--reduced100m"] + argv

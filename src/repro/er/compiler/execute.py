@@ -45,6 +45,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ...kernels.ops import resolve_impl
+from ...kernels.pair_sim import resolve_capacity
 from .comms import (COMMS_POLICIES, CommsPlan, halo_bytes_per_device,
                     plan_comms, psum_bytes_per_device, rewrite_tiles_local)
 from .faults import DeviceKilledError, FaultInjector, TransientScorerError
@@ -70,38 +72,13 @@ __all__ = [
 ]
 
 
-# shard_map moved from jax.experimental to the top-level namespace (with
-# check_rep renamed check_vma) across the jax versions we support; every
-# shard_map call site in the repo goes through this shim.
-try:
-    _shard_map_new = jax.shard_map
-
-    def _smap(f, mesh, in_specs, out_specs):
-        return _shard_map_new(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def _smap(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
-
-def _resolve_impl(impl: str) -> str:
-    if impl == "auto":
-        # Interpret-mode Pallas is a Python emulator — on a non-TPU
-        # backend the batched-matmul XLA path IS the production path.
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    return impl
-
-
 def _compact_on_device(impl: str) -> bool:
-    """True when ``impl`` is a compiled backend whose on-device packing
-    epilogue beats a host mask scan. Interpret mode emulates the kernel
-    in Python — the one-hot packing epilogue is O(bm·bn·capacity) numpy
-    per tile there, so the dense mask (+ np.nonzero) is the honest path."""
-    return impl == "xla" or (impl == "pallas"
-                             and jax.default_backend() == "tpu")
+    """True when the resolved ``impl`` is a compiled backend, whose
+    on-device packing epilogue beats a host mask scan. Interpret mode
+    emulates the kernel in Python — the one-hot packing epilogue is
+    O(bm·bn·capacity) numpy per tile there, so the dense mask
+    (+ np.nonzero) is the honest path."""
+    return impl in ("xla", "pallas")
 
 
 def _pad_pow2(t: int, cap: int) -> int:
@@ -127,7 +104,7 @@ def _pad_pow2(t: int, cap: int) -> int:
 # the exact formulas of ``compiler.comms`` — mesh_bench asserts the
 # ring/flat ratio on these counters.
 stage1_stats: dict = {"compact_decodes": 0, "nonzero_decodes": 0,
-                      "compact_overflows": 0,
+                      "compact_overflows": 0, "survivors": 0,
                       "interconnect": {"flat_bytes": 0, "ring_bytes": 0,
                                        "hier_intra_bytes": 0,
                                        "hier_inter_bytes": 0,
@@ -151,6 +128,17 @@ def _decode_packed(packed: np.ndarray, counts: np.ndarray,
     return rows_a, rows_b
 
 
+def _survivors(out_a: List[np.ndarray], out_b: List[np.ndarray]
+               ) -> Tuple[np.ndarray, np.ndarray]:
+    """Concatenate decoded stage-1 survivors, counted in
+    ``stage1_stats["survivors"]``."""
+    if not out_a:
+        return np.zeros(0, np.int64), np.zeros(0, np.int64)
+    ra, rb = np.concatenate(out_a), np.concatenate(out_b)
+    stage1_stats["survivors"] += ra.size
+    return ra, rb
+
+
 def score_catalog(feats_a, catalog: TileCatalog, feats_b=None, *,
                   threshold: float, impl: str = "auto",
                   chunk_tiles: int = 1024, compact: bool = True,
@@ -167,15 +155,16 @@ def score_catalog(feats_a, catalog: TileCatalog, feats_b=None, *,
     O(survivors); interpret mode keeps the dense-mask + ``np.nonzero``
     path (a Python emulator gains nothing from an emulated epilogue).
 
-    ``compact_capacity`` bounds the packed slots per tile (default
-    bm·bn, which can never overflow). A smaller capacity shrinks the
-    device→host transfer; tiles whose EXACT count exceeds it fall back
-    to the mask path for that chunk — still exact, counted in
-    ``stage1_stats['compact_overflows']``. Returns two int64 arrays.
+    ``compact_capacity`` bounds the packed slots per tile (default 1,024;
+    either way capped at bm·bn by
+    :func:`~...kernels.pair_sim.resolve_capacity`). Tiles whose EXACT
+    count exceeds it fall back to the mask path for that chunk — still
+    exact, counted in ``stage1_stats['compact_overflows']``. Returns two
+    int64 arrays.
     """
     from ...kernels import ops
 
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     if feats_b is None:
         feats_b = feats_a
     fa = jnp.asarray(feats_a)
@@ -184,7 +173,7 @@ def score_catalog(feats_a, catalog: TileCatalog, feats_b=None, *,
     bm, bn = catalog.block_m, catalog.block_n
     t_total = tiles.shape[0]
     use_compact = compact and _compact_on_device(impl)
-    capacity = compact_capacity if compact_capacity is not None else bm * bn
+    capacity = resolve_capacity(bm, bn, compact_capacity)
     out_a, out_b = [], []
     for lo in range(0, t_total, chunk_tiles):
         chunk = tiles[lo:lo + chunk_tiles]
@@ -216,9 +205,7 @@ def score_catalog(feats_a, catalog: TileCatalog, feats_b=None, *,
         ti, ii, jj = np.nonzero(mask)
         out_a.append(chunk[ti, A_TILE].astype(np.int64) * bm + ii)
         out_b.append(chunk[ti, B_TILE].astype(np.int64) * bn + jj)
-    if not out_a:
-        return np.zeros(0, np.int64), np.zeros(0, np.int64)
-    return np.concatenate(out_a), np.concatenate(out_b)
+    return _survivors(out_a, out_b)
 
 
 # ---------------------------------------------------------------------------
@@ -312,14 +299,15 @@ def make_scorer(mesh: Mesh, axis: str = "data", *, mode: str = "self",
     :func:`_compact_on_device`), (n_dev, chunk, capacity) packed slot
     ids + (n_dev, chunk, 1) exact counts from the kernel's on-device
     compaction epilogue, so the host decode is O(survivors) with no
-    ``np.nonzero``. ``capacity`` defaults to bm·bn, which can never
-    overflow. Build the scorer once per resident service / driver and
+    ``np.nonzero``. ``capacity`` defaults as in :func:`score_catalog`;
+    a chunk that overflows it re-scores through the mask twin. Build the
+    scorer once per resident service / driver and
     reuse it: jit caches by the wrapped function's identity, so a
     per-call closure would retrace every batch.
     """
     from ...kernels import ops, ref
 
-    cap = capacity if capacity is not None else block_m * block_n
+    cap = resolve_capacity(block_m, block_n, capacity)
     if comms not in COMMS_POLICIES:
         raise ValueError(f"unknown comms policy {comms!r}")
     if comms != "flat" and mode == "halo":
@@ -423,7 +411,8 @@ def make_scorer(mesh: Mesh, axis: str = "data", *, mode: str = "self",
     else:
         raise ValueError(f"unknown scorer mode {mode!r}")
 
-    fn = jax.jit(_smap(job2, mesh, in_specs=in_specs, out_specs=out_specs))
+    fn = jax.jit(jax.shard_map(job2, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False))
     mask_factory = (
         (lambda: make_scorer(mesh, axis, mode=mode, threshold=threshold,
                              block_m=block_m, block_n=block_n, impl=impl,
@@ -448,10 +437,9 @@ def _score_and_compact(shard, operands, tiles_dev, chunk: int,
     Compact scorers (:class:`CatalogScorer` with ``compact=True``, the
     default on compiled backends) decode the kernel's packed survivor
     slots per device — O(survivors) host work, no ``np.nonzero``; a tile
-    whose exact count exceeds the capacity (only possible with a
-    user-bounded capacity) re-scores that chunk through the lazily built
-    mask twin, exactness over speed. Both paths are counted in
-    ``stage1_stats``. ``base_a``/``base_b`` (n_dev,) shift device-local
+    whose exact count exceeds the capacity re-scores that chunk through
+    the lazily built mask twin, exactness over speed. Both paths are
+    counted in ``stage1_stats``. ``base_a``/``base_b`` (n_dev,) shift device-local
     tile coordinates to global rows on each side (the RepSN and
     ring/hierarchical local-coordinate paths — cross-mode ring shifts
     the a-side only, since the b operand was never rewritten); None
@@ -502,9 +490,7 @@ def _score_and_compact(shard, operands, tiles_dev, chunk: int,
         off_b = base_b[d] if base_b is not None else 0
         out_a.append(off_a + part[d, ti, A_TILE].astype(np.int64) * bm + ii)
         out_b.append(off_b + part[d, ti, B_TILE].astype(np.int64) * bn + jj)
-    if not out_a:
-        return np.zeros(0, np.int64), np.zeros(0, np.int64)
-    return np.concatenate(out_a), np.concatenate(out_b)
+    return _survivors(out_a, out_b)
 
 
 def _tiles_by_device(catalog: TileCatalog, n_dev: int,
@@ -650,7 +636,7 @@ def execute(catalog: TileCatalog, feats_a, feats_b=None, *,
     if scorer is None:
         mode = "halo" if halo > 0 else ("cross" if feats_b is not None
                                         else "self")
-        rimpl = _resolve_impl(impl)
+        rimpl = resolve_impl(impl)
         scorer = make_scorer(mesh, axis, mode=mode, threshold=threshold,
                              block_m=bm, block_n=bn, impl=rimpl, halo=halo,
                              compact=compact and _compact_on_device(rimpl),

@@ -25,12 +25,15 @@ hillclimb with two signals:
     ones are bridged through a fitted seconds-per-model-unit scale so
     one measurement anywhere wall-clock-anchors the whole lattice.
 
-Candidates whose double-buffered working set exceeds the VMEM budget
-(:func:`~...kernels.pair_sim.catalog_vmem_bytes`) are dropped before
-scoring. The lattice is finite and every geometry is a static kernel
-arg, so a resident service compiles at most |lattice| variants during
-its warmup sweep and then pins the winner — zero steady-state
-recompiles (asserted by benchmarks/tune_bench.py).
+Candidates are dropped before scoring when the VMEM working set
+(:func:`~...kernels.pair_sim.catalog_vmem_bytes`) of either stage-1
+kernel they would run — the compact kernel at its resolved capacity
+(:func:`~...kernels.pair_sim.resolve_capacity`), or the mask kernel an
+overflow falls back to — exceeds the budget. The lattice is finite and
+every geometry is a static kernel arg, so a resident service compiles
+at most |lattice| variants during its warmup sweep and then pins the
+winner — zero steady-state recompiles (asserted by
+benchmarks/tune_bench.py).
 """
 from __future__ import annotations
 
@@ -41,7 +44,7 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 from ...kernels.pair_sim import (GEOMETRY_LATTICE, VMEM_BUDGET_BYTES,
-                                 catalog_vmem_bytes)
+                                 catalog_vmem_bytes, resolve_capacity)
 from .feedback import GeometryCostModel
 from .ir import MatchJob, TileCatalog
 from .lower import lower
@@ -130,14 +133,16 @@ def _score_one(job: MatchJob, bm: int, bn: int, beta: float,
 
 def autotune(job: MatchJob, *,
              lattice: Sequence[Tuple[int, int]] = GEOMETRY_LATTICE,
-             d: int = 0, capacity: int = 0, beta: float = DEFAULT_BETA,
+             d: int = 0, capacity: Optional[int] = None,
+             beta: float = DEFAULT_BETA,
              tile_overhead: float = DEFAULT_TILE_OVERHEAD,
              feedback: Optional[GeometryCostModel] = None) -> TuneReport:
     """Choose (block_m, block_n) for ``job`` from ``lattice``.
 
-    ``d`` (feature dim) and ``capacity`` (compaction slots), when given,
-    drop candidates whose double-buffered VMEM working set exceeds the
-    budget. With a :class:`GeometryCostModel` holding at least one
+    ``d`` (feature dim), when given, drops candidates whose compact
+    kernel (at ``capacity`` as the executor resolves it) or mask kernel
+    would not fit VMEM.
+    With a :class:`GeometryCostModel` holding at least one
     measured lattice candidate, ranking is by predicted wall seconds —
     measured candidates at ``rate · live_pairs``, unmeasured ones
     bridged via the fitted seconds-per-model-unit of the measured set.
@@ -145,7 +150,9 @@ def autotune(job: MatchJob, *,
     """
     cands = []
     for bm, bn in lattice:
-        if d and catalog_vmem_bytes(bm, bn, d, capacity) > VMEM_BUDGET_BYTES:
+        cap = resolve_capacity(bm, bn, capacity)
+        if d and max(catalog_vmem_bytes(bm, bn, d, cap),
+                     catalog_vmem_bytes(bm, bn, d)) > VMEM_BUDGET_BYTES:
             continue
         cands.append((int(bm), int(bn)))
     if not cands:

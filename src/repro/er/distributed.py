@@ -45,7 +45,7 @@ from .compiler import (DeviceKilledError, FaultEvent, FaultInjector,
                        execute_supervised, lower, make_scorer, pad_tiles,
                        shard_sane, tiles_for_devices)
 from .compiler.comms import halo_bytes_per_device
-from .compiler.execute import _score_and_compact, _smap
+from .compiler.execute import _score_and_compact
 from .compiler.ir import make_job, task_row
 from .similarity import two_stage_match
 
@@ -91,7 +91,8 @@ def compute_bdm_sharded(block_ids, num_blocks: int, mesh: Mesh,
         cols = jax.lax.all_gather(col, axis)          # (m, b)
         return cols.T.astype(jnp.int32)               # (b, m)
 
-    shard = _smap(job1, mesh, in_specs=P(axis), out_specs=P())
+    shard = jax.shard_map(job1, mesh=mesh, in_specs=P(axis), out_specs=P(),
+                          check_vma=False)
     return shard(block_ids)
 
 
@@ -326,9 +327,10 @@ def match_pair_range_dist(feats, codes, lens, plan: PairRangePlan,
         out = lambda x: x[None]  # (1, cap) per device → (n_dev, cap) stacked
         return out(ra), out(rb), out(mask), out(score)
 
-    shard = _smap(job2, mesh,
-                  in_specs=(P(axis), P(axis), P(axis)),
-                  out_specs=(P(axis), P(axis), P(axis), P(axis)))
+    shard = jax.shard_map(job2, mesh=mesh,
+                          in_specs=(P(axis), P(axis), P(axis)),
+                          out_specs=(P(axis), P(axis), P(axis), P(axis)),
+                          check_vma=False)
     return shard(feats, codes, lens)
 
 
@@ -349,7 +351,8 @@ def match_shards_hostplan(feats, codes, lens, rows_a, rows_b, valid,
             threshold, filter_margin)
         return mask[None], score[None]
 
-    shard = _smap(job2, mesh,
-                  in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis)),
-                  out_specs=(P(axis), P(axis)))
+    shard = jax.shard_map(
+        job2, mesh=mesh,
+        in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis)),
+        out_specs=(P(axis), P(axis)), check_vma=False)
     return shard(feats, codes, lens, rows_a, rows_b, valid)

@@ -24,7 +24,6 @@ from .compiler import (  # noqa: F401
     score_catalog,
     verify_pairs,
 )
-from .compiler.execute import _resolve_impl  # noqa: F401  (service shim)
 from .compiler.ir import NO_LB as _NO_LB, NO_UB as _NO_UB  # noqa: F401
 from .compiler.lower import pad_catalog as pad_catalog_tiles  # noqa: F401
 

@@ -118,8 +118,8 @@ class ERConfig:
     steal_factor: Optional[float] = None   # > 0: mid-stream work stealing
     steal_quantum: Optional[int] = None    # tiles per dispatch batch
     # ---- stage-1 survivor compaction (catalog executor) ----
-    compact_capacity: Optional[int] = None  # packed slots per tile;
-                                            # None = bm·bn (never overflows)
+    compact_capacity: Optional[int] = None  # packed slots per tile; None
+                                            # = pair_sim.resolve_capacity
 
 
 @dataclass
@@ -381,7 +381,7 @@ def run_er(titles: Sequence[str], config: Optional[ERConfig] = None,
         if not cfg.tune_tiles:
             return cfg.block_m, cfg.block_n
         rep = autotune(job, d=cfg.feature_dim,
-                       capacity=cfg.compact_capacity or 0)
+                       capacity=cfg.compact_capacity)
         extra.setdefault("tuned_geometry", {})[
             f"job{len(extra['tuned_geometry'])}"] = rep.geometry
         return rep.geometry

@@ -68,7 +68,8 @@ from .compiler import (COMMS_POLICIES, GEOMETRY_LATTICE, DeviceKilledError,
                        autotune, cross_job, default_group, execute,
                        execute_supervised, lower, make_scorer, pad_catalog,
                        plan_comms, plan_to_job, schedule_tiles, verify_pairs)
-from .compiler.execute import _compact_on_device, _resolve_impl
+from ..kernels.ops import resolve_impl
+from .compiler.execute import _compact_on_device
 from .compiler.faults import FaultInjector
 from .pipeline import featurize
 
@@ -89,18 +90,6 @@ def _on_compile_event(name: str, *args, **kwargs):
                 counter.count += 1
 
 
-def _unregister_compile_listener() -> bool:
-    """Best-effort unregister (jax exposes the hook privately); returns
-    whether the listener was actually removed."""
-    try:
-        from jax._src import monitoring as _monitoring
-        _monitoring._unregister_event_duration_listener_by_callback(
-            _on_compile_event)
-        return True
-    except Exception:
-        return False
-
-
 class compile_counter:
     """Count XLA backend compilations inside a ``with`` block via
     ``jax.monitoring`` duration events — cache hits emit none, so after a
@@ -108,9 +97,8 @@ class compile_counter:
     recompile guard the tests and the serve benchmark assert).
 
     Thread-safe and re-entrant: the module-level listener is registered
-    while any counter is live and unregistered when the last one exits
-    (falling back to keep-registered on jax versions without the
-    unregister hook), subscription and increments share one lock, and
+    while any counter is live and unregistered when the last one exits,
+    subscription and increments share one lock, and
     the same instance can be nested — the count resets only on the
     outermost ``__enter__``. Counters are global: a counter sees
     compilations triggered by *other* threads while it is open, which is
@@ -139,8 +127,9 @@ class compile_counter:
             self._depth -= 1
             if self._depth <= 0:
                 _ACTIVE_COUNTERS.discard(self)
-                if not _ACTIVE_COUNTERS and _listener_registered \
-                        and _unregister_compile_listener():
+                if not _ACTIVE_COUNTERS and _listener_registered:
+                    jax.monitoring.unregister_event_duration_listener(
+                        _on_compile_event)
                     _listener_registered = False
         return False
 
@@ -268,8 +257,9 @@ class ServiceConfig:
     query_buckets: Tuple[int, ...] = (8, 32, 128, 512)  # batch pad sizes
     tile_chunk: int = 256                 # fixed catalog chunk (tiles/launch)
     compact_capacity: Optional[int] = None  # stage-1 packed survivor slots
-                                            # per tile; None = bm·bn (the
-                                            # no-overflow default)
+                                            # per tile; None = the
+                                            # pair_sim.resolve_capacity
+                                            # default
     schedule_policy: str = "cost_lpt"     # cost_lpt | round_robin
     comms: str = "flat"                   # mesh= gather policy for keyed
                                           # jobs: flat | ring |
@@ -439,7 +429,7 @@ class ERService:
         if self.mesh is None:
             return
         cfg = self.cfg
-        rimpl = _resolve_impl(cfg.kernel_impl)
+        rimpl = resolve_impl(cfg.kernel_impl)
         self._dist_scorer = make_scorer(
             self.mesh, self.axis, mode="cross", threshold=self._stage1,
             block_m=self._block_m, block_n=self._block_n, impl=rimpl,
@@ -950,7 +940,7 @@ class ERService:
         if job is None or job.total_pairs == 0:
             return
         kwargs = dict(lattice=cfg.autotune_lattice, d=cfg.feature_dim,
-                      capacity=cfg.compact_capacity or 0,
+                      capacity=cfg.compact_capacity,
                       feedback=self.geometry_feedback)
         if self.geometry_feedback.best(cfg.autotune_lattice) is None:
             report = autotune(job, **kwargs)

@@ -23,13 +23,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.pair_sim import SCORE_PRECISION
+
 __all__ = ["cosine_scores", "edit_distance", "edit_similarity",
            "two_stage_match", "edit_distance_np"]
 
 
 def cosine_scores(a, b):
     """(P, d) × (P, d) row-paired cosine scores (features pre-normalized)."""
-    return jnp.einsum("pd,pd->p", a, b, preferred_element_type=jnp.float32)
+    return jnp.einsum("pd,pd->p", a, b, precision=SCORE_PRECISION,
+                      preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=())

@@ -1,13 +1,13 @@
 """jit'd public wrappers around the Pallas kernels, with XLA fallbacks.
 
 Every op takes ``impl``:
-  * "pallas"    — the TPU kernel (compiled; TPU target),
-  * "interpret" — the kernel body interpreted on CPU (correctness path,
-                  used by tests on this CPU-only container),
-  * "xla"       — the pure-jnp reference (dry-run / fallback).
-
-On a CPU backend "pallas" silently degrades to "interpret" so example
-scripts run anywhere.
+  * "pallas"    — the TPU kernel (compiled; TPU target). Raises when
+                  JAX's default backend is not a TPU: nothing silently
+                  stands in for the chip.
+  * "interpret" — the kernel body interpreted on the host (correctness
+                  path of the CPU tests),
+  * "xla"       — the pure-jnp reference,
+  * "auto"      — "pallas" on a TPU, "xla" elsewhere.
 """
 from __future__ import annotations
 
@@ -16,6 +16,7 @@ import jax
 from . import ref
 from .flash_attention import flash_attention as _flash
 from .grouped_mm import grouped_matmul as _gmm, pad_groups  # noqa: F401
+from .pair_sim import COMPACT_CAPACITY
 from .pair_sim import pair_scores as _pair_scores
 from .pair_sim import pair_scores_catalog as _pair_scores_catalog
 from .pair_sim import \
@@ -23,18 +24,26 @@ from .pair_sim import \
 
 __all__ = ["pair_scores", "pair_scores_catalog",
            "pair_scores_catalog_raw", "pair_scores_catalog_compact",
-           "grouped_matmul", "attention", "pad_groups"]
+           "grouped_matmul", "attention", "pad_groups", "resolve_impl"]
 
 
-def _resolve(impl: str) -> str:
-    if impl == "pallas" and jax.default_backend() != "tpu":
-        return "interpret"
+def resolve_impl(impl: str) -> str:
+    """Resolve ``"auto"`` against the default backend and refuse
+    ``"pallas"`` off the TPU."""
+    backend = jax.default_backend()
+    if impl == "auto":
+        return "pallas" if backend == "tpu" else "xla"
+    if impl == "pallas" and backend != "tpu":
+        raise RuntimeError(
+            f"impl='pallas' needs a TPU, but JAX's default backend is "
+            f"{backend!r}; pass impl='interpret' or 'auto' to run "
+            f"without one")
     return impl
 
 
 def pair_scores(a, b, *, threshold: float = 0.8, triangular: bool = False,
                 block_m: int = 128, block_n: int = 128, impl: str = "pallas"):
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.pair_scores_ref(a, b, threshold=threshold, triangular=triangular)
     return _pair_scores(a, b, threshold=threshold, triangular=triangular,
@@ -48,7 +57,7 @@ def pair_scores_catalog(a, b, catalog, *, threshold: float = 0.8,
     """Tile-catalog survivor masks (see pair_sim.pair_scores_catalog).
     ``impl="xla"`` is the production CPU path (batched dynamic-slice
     matmul), not just a test oracle — interpret mode is Python-slow."""
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.pair_scores_catalog_ref(
             a, b, catalog, threshold=threshold,
@@ -75,12 +84,13 @@ def pair_scores_catalog_raw(a, b, catalog, *, block_m: int = 128,
 
 def pair_scores_catalog_compact(a, b, catalog, *, threshold: float = 0.8,
                                 block_m: int = 128, block_n: int = 128,
-                                capacity: int = 1024, impl: str = "pallas"):
+                                capacity: int = COMPACT_CAPACITY,
+                                impl: str = "pallas"):
     """Tile-catalog survivors packed on device (see
     pair_sim.pair_scores_catalog_compact): ``(packed, counts)`` instead
     of a dense mask — the serving stage 1 uses this so the host never
     runs ``np.nonzero`` over T·bm·bn cells."""
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.pair_scores_catalog_compact_ref(
             a, b, catalog, threshold=threshold,
@@ -93,7 +103,7 @@ def pair_scores_catalog_compact(a, b, catalog, *, threshold: float = 0.8,
 
 def grouped_matmul(x, tile_expert, w, *, block_t: int = 128,
                    block_f: int = 128, impl: str = "pallas"):
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.grouped_matmul_ref(x, tile_expert, w, block_t=block_t)
     return _gmm(x, tile_expert, w, block_t=block_t, block_f=block_f,
@@ -102,7 +112,7 @@ def grouped_matmul(x, tile_expert, w, *, block_t: int = 128,
 
 def attention(q, k, v, *, causal: bool = True, scale: float | None = None,
               block_q: int = 512, block_k: int = 512, impl: str = "xla"):
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.attention_ref(q, k, v, causal=causal, scale=scale)
     return _flash(q, k, v, causal=causal, scale=scale,

@@ -18,17 +18,27 @@ DMA: inputs stay in HBM (``memory_space=ANY``); two-deep VMEM strip
 buffers prefetch tile t+1's LHS/RHS strips while tile t computes, so the
 strip copy-in overlaps the MXU work instead of serializing ahead of it.
 
-VMEM per step, double-buffered (f32, d feature dim):
+VMEM per step (f32, d feature dim), as Mosaic's compiler for TPU v5e
+counts it against its 16 MiB default scoped-VMEM limit:
   strips   2 · (bm + bn) · d · 4 B          (two slots each side)
-  compute  ≈ 4 · bm · bn · 4 B              (scores, mask, dest, flat)
-  epilogue (compact only)
-           (bm² + bn² + capacity · bn + capacity) · 4 B
-Worst lattice candidate (bm = bn = 256, d = 256, capacity = 1024):
-  2·(512)·256·4 ≈ 1.0 MiB strips + 1.0 MiB compute + 1.3 MiB epilogue
-  ≈ 3.3 MiB — under the ``VMEM_BUDGET_BYTES`` bound asserted at lowering
-time by :func:`check_vmem` (the ~16 MiB/core physical budget minus
-headroom for compiler temporaries). :func:`catalog_vmem_bytes` is the
-shared model; er/compiler/tune.py filters lattice candidates with it.
+  score    2 · (bm + bn) · d · 4 B          (the full-f32 contraction's
+           + bm · ⌈bn⌉₁₂₈ · 4 B             operand splits, accumulator)
+  mask     2 · bm · ⌈bn⌉₁₂₈ · 4 B          (double-buffered output block,
+                                             lanes padded to 128)
+  compact  (bm² + bn² + bm·bn) · 4 B        (prefix-sum planes, slot ids)
+           + 576 B per packed slot          (the row loop's (1, capacity)
+                                             values, sublane-padded)
+:func:`catalog_vmem_bytes` is that model plus 64 KiB of fixed overhead,
+fitted to the compiler's own figures over every lattice geometry at
+d = 256 (capacities 0–16,384 slots, never more than the tile's bm·bn
+cells) and at d = 128 and 512 on six of them, and never below them, so
+whatever :func:`check_vmem` admits under ``VMEM_BUDGET_BYTES``
+compiles. The score term is loose for tiles no wider than 128 (the
+compiler keeps those splits out of scoped VMEM) and within 3 % of the
+compiler at 256 × 256.
+Worst lattice candidate at the default capacity (256 × 256, d = 256,
+1,024 slots): ≈ 3.6 MiB. er/compiler/tune.py filters lattice candidates
+with the same model.
 
 Two entry points:
   * :func:`pair_scores` — dense (M, N) scoring of two full matrices
@@ -47,6 +57,7 @@ Two entry points:
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -54,8 +65,17 @@ from jax.experimental import pallas as pl
 
 __all__ = ["pair_scores", "pair_scores_catalog",
            "pair_scores_catalog_compact", "catalog_tile_mask", "NCOLS",
-           "GEOMETRY_LATTICE", "VMEM_BUDGET_BYTES", "catalog_vmem_bytes",
+           "GEOMETRY_LATTICE", "VMEM_BUDGET_BYTES", "COMPACT_CAPACITY",
+           "SCORE_PRECISION", "resolve_capacity", "catalog_vmem_bytes",
            "check_vmem"]
+
+# Every cosine score — the kernels here, their XLA twins and the paired
+# dots of the distributed executors — is a full-f32 contraction, as the
+# host reference's ``np.einsum`` is. At Mosaic's default precision the
+# DS1-sized corpus kept 58,235 stage-1 survivors on a TPU v5e where the
+# reference keeps 58,289, and the final match set lost two pairs and
+# gained one.
+SCORE_PRECISION = jax.lax.Precision.HIGHEST
 
 # Catalog entry layout (int32 columns) — shared with er/executor.py and
 # kernels/ref.py. Rows/cols below are *global* row indices of the feature
@@ -82,28 +102,61 @@ GEOMETRY_LATTICE = ((32, 32), (32, 64), (32, 128), (32, 256),
                     (128, 32), (128, 64), (128, 128), (128, 256),
                     (256, 32), (256, 64), (256, 128), (256, 256))
 
-# ~16 MiB/core physical VMEM minus headroom for Mosaic temporaries.
+# Mosaic's default scoped-VMEM limit on v5e is 16 MiB; the budget keeps
+# headroom below it for compiler temporaries the model does not itemize.
 VMEM_BUDGET_BYTES = 12 * 2 ** 20
+
+# Lane width of a TPU vreg: the compact kernel's per-tile count row is
+# one lane-dense (1, _LANES) block (Mosaic refuses a (1, 1) block), and
+# a mask block narrower than a vreg still occupies whole lanes in VMEM.
+_LANES = 128
+
+# Packed survivor slots per tile of the compact kernel. The epilogue's
+# work and VMEM grow linearly in the capacity, and a tile past it only
+# costs an exact mask-path re-score of its chunk. The DS1-scale corpus
+# (``make_products(114_000)``) peaks at 265 stage-1 survivors in a
+# 128 × 128 tile, so 1,024 slots leave about 4× headroom.
+COMPACT_CAPACITY = 1024
+
+
+def resolve_capacity(block_m: int, block_n: int,
+                     capacity: Optional[int] = None) -> int:
+    """The capacity the compact kernel runs at for a (block_m, block_n)
+    tile: ``capacity`` (default ``COMPACT_CAPACITY``) capped at the
+    tile's bm·bn cells — more slots than cells can hold nothing. The
+    executor, the mesh scorer and the autotuner's VMEM filter all
+    resolve it here."""
+    if capacity is None:
+        capacity = COMPACT_CAPACITY
+    return min(block_m * block_n, int(capacity))
 
 
 def catalog_vmem_bytes(block_m: int, block_n: int, d: int,
                        capacity: int = 0) -> int:
-    """Worst-case VMEM bytes one grid step of the catalog kernels holds
-    live: double-buffered strips + compute planes (+ compaction epilogue
-    when ``capacity`` > 0). Shared with er/compiler/tune.py, which drops
-    lattice candidates this model puts over ``VMEM_BUDGET_BYTES``."""
+    """VMEM bytes the compiler scopes for one catalog kernel: the mask
+    kernel when ``capacity`` is 0, the compact kernel otherwise (see the
+    module docstring for the fit). Shared with er/compiler/tune.py,
+    which drops lattice candidates this model puts over
+    ``VMEM_BUDGET_BYTES``."""
+    lanes_n = -(-block_n // _LANES) * _LANES
     strips = 2 * (block_m + block_n) * d * 4
-    compute = 4 * block_m * block_n * 4
-    epilogue = 0
+    score = strips + block_m * lanes_n * 4
     if capacity:
-        epilogue = (block_m * block_m + block_n * block_n
-                    + capacity * block_n + capacity) * 4
-    return strips + compute + epilogue
+        body = ((block_m * block_m + block_n * block_n
+                 + block_m * block_n) * 4 + 576 * capacity)
+    else:
+        body = 2 * block_m * lanes_n * 4
+    return strips + score + body + 64 * 2 ** 10
 
 
 def check_vmem(block_m: int, block_n: int, d: int, capacity: int = 0) -> None:
     """Lowering-time guard: raise before tracing a kernel whose step
-    working set cannot fit VMEM."""
+    working set cannot fit VMEM, or a compact kernel with more slots than
+    its tile has cells (outside the model's fit, and never useful)."""
+    if capacity > block_m * block_n:
+        raise ValueError(
+            f"capacity={capacity} exceeds the {block_m}x{block_n} tile's "
+            f"{block_m * block_n} cells; see resolve_capacity")
     need = catalog_vmem_bytes(block_m, block_n, d, capacity)
     if need > VMEM_BUDGET_BYTES:
         raise ValueError(
@@ -132,7 +185,7 @@ def _kernel(a_ref, b_ref, o_ref, *, threshold: float, triangular: bool,
     a = a_ref[...]                       # (block_m, d)
     b = b_ref[...]                       # (block_n, d)
     s = jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())),
+        a, b, (((1,), (1,)), ((), ())), precision=SCORE_PRECISION,
         preferred_element_type=jnp.float32)        # (block_m, block_n) MXU
     keep = s >= threshold
     if triangular:
@@ -230,7 +283,7 @@ def _entry_keep(cat_ref, a, b, *, threshold: float, block_m: int,
     """Score the current entry's strips and apply its predicate."""
     t = pl.program_id(0)
     s = jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())),
+        a, b, (((1,), (1,)), ((), ())), precision=SCORE_PRECISION,
         preferred_element_type=jnp.float32)        # (block_m, block_n) MXU
     entry = [cat_ref[t, c] for c in range(NCOLS)]
     gi = entry[0] * block_m + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -253,8 +306,8 @@ def _catalog_specs(block_m: int, block_n: int, d: int, a_dtype, b_dtype):
     itself via :func:`_load_strips`."""
     from jax.experimental.pallas import tpu as pltpu
 
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY)]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
     scratch = [pltpu.VMEM((2, block_m, d), a_dtype),
                pltpu.VMEM((2, block_n, d), b_dtype),
                pltpu.SemaphoreType.DMA((2,)),
@@ -309,8 +362,9 @@ def pair_scores_catalog(a, b, catalog, *, threshold: float = 0.8,
 
 
 def _catalog_compact_kernel(cat_ref, a_hbm, b_hbm, packed_ref, count_ref,
-                            a_buf, b_buf, a_sem, b_sem, *, threshold: float,
-                            block_m: int, block_n: int, capacity: int):
+                            a_buf, b_buf, a_sem, b_sem, dest_ref, *,
+                            threshold: float, block_m: int, block_n: int,
+                            capacity: int):
     a, b = _load_strips(cat_ref, a_hbm, b_hbm, a_buf, b_buf, a_sem, b_sem,
                         block_m, block_n)
     keep = _entry_keep(cat_ref, a, b, threshold=threshold,
@@ -319,44 +373,50 @@ def _catalog_compact_kernel(cat_ref, a_hbm, b_hbm, packed_ref, count_ref,
 
     # Row-major survivor ranks without scatter/sort (neither lowers to
     # Mosaic): prefix sums become triangular-ones matmuls, MXU-native.
-    # Ranks stay exact in f32 — they are integers < bm·bn ≤ 2^24.
+    # Every operand is 0/1, so the sums are exact at any MXU precision.
     cc = jax.lax.broadcasted_iota(jnp.int32, (block_n, block_n), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (block_n, block_n), 1)
     upper = (cc < jj).astype(jnp.float32)          # strict upper (bn, bn)
     excl = jax.lax.dot_general(                    # within-row exclusive
         kf, upper, (((1,), (0,)), ((), ())),       # prefix of the mask
         preferred_element_type=jnp.float32)
-    row_tot = jnp.sum(kf, axis=1, keepdims=True)   # (bm, 1)
     ii = jax.lax.broadcasted_iota(jnp.int32, (block_m, block_m), 0)
     rr = jax.lax.broadcasted_iota(jnp.int32, (block_m, block_m), 1)
     lower = (rr < ii).astype(jnp.float32)          # strict lower (bm, bm)
-    row_off = jax.lax.dot_general(                 # rows-above totals
-        lower, row_tot, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)        # (bm, 1)
-    dest = jnp.where(keep, row_off + excl, -1.0)   # pack slot, −1 = dead
+    above = jax.lax.dot_general(                   # survivors in rows
+        lower, kf, (((1,), (0,)), ((), ())),       # above, per column
+        preferred_element_type=jnp.float32)
+    row_off = jnp.sum(above, axis=1, keepdims=True)            # (bm, 1)
+    dest_ref[...] = jnp.where(keep, row_off + excl,
+                              -1.0).astype(jnp.int32)  # slot, −1 = dead
 
-    li = jax.lax.broadcasted_iota(jnp.int32, keep.shape, 0)
-    lj = jax.lax.broadcasted_iota(jnp.int32, keep.shape, 1)
-    flat = (li * block_n + lj).astype(jnp.float32)  # tile-local pair id
-
-    # packed[k] = Σ_p [dest_p == k] · flat_p — a one-hot contraction per
-    # row keeps the (capacity, bn) one-hot plane VMEM-resident. Slots
-    # beyond the survivor count (and anything past ``capacity``) simply
-    # accumulate nothing and stay 0.
-    k_iota = jax.lax.broadcasted_iota(jnp.float32, (capacity, block_n), 0)
+    # packed[k] = Σ_p [dest_p == k] · (r·bn + j_p), one row r at a time:
+    # the (capacity, bn) one-hot plane of row r contracts against
+    # [column ids; ones], and the row offset r·bn is added after the
+    # dot, so the MXU only ever sees integers ≤ 255 (exact in bf16).
+    # Slots beyond the survivor count (and survivors past ``capacity``)
+    # match nothing and stay 0.
+    k_iota = jax.lax.broadcasted_iota(jnp.int32, (capacity, block_n), 0)
+    sel = jax.lax.broadcasted_iota(jnp.int32, (2, block_n), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (2, block_n), 1)
+    lhs = jnp.where(sel == 0, cols, 1).astype(jnp.float32)   # (2, bn)
 
     def row(r, acc):
-        d_r = jax.lax.dynamic_slice(dest, (r, 0), (1, block_n))
-        v_r = jax.lax.dynamic_slice(flat, (r, 0), (1, block_n))
-        onehot = (d_r == k_iota).astype(jnp.float32)   # (capacity, bn)
-        return acc + jax.lax.dot_general(
-            v_r, onehot, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (1, capacity)
+        d_r = dest_ref[pl.ds(r, 1), :]                       # (1, bn)
+        onehot = (d_r == k_iota).astype(jnp.float32)         # (capacity, bn)
+        got = jax.lax.dot_general(
+            lhs, onehot, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (2, capacity)
+        base = (r * block_n).astype(jnp.float32)
+        return acc + got[0:1] + base * got[1:2]
 
     acc = jax.lax.fori_loop(
         0, block_m, row, jnp.zeros((1, capacity), jnp.float32))
-    packed_ref[...] = acc.astype(jnp.int32)
-    count_ref[...] = jnp.sum(kf).astype(jnp.int32)[None, None]
+    packed_ref[...] = acc.astype(jnp.int32)[None]
+    count = jnp.sum(jnp.sum(kf, axis=1, keepdims=True), axis=0,
+                    keepdims=True)                           # (1, 1)
+    count_ref[...] = jnp.broadcast_to(count.astype(jnp.int32),
+                                      (1, _LANES))[None]
 
 
 @functools.partial(
@@ -364,7 +424,7 @@ def _catalog_compact_kernel(cat_ref, a_hbm, b_hbm, packed_ref, count_ref,
                               "interpret"))
 def pair_scores_catalog_compact(a, b, catalog, *, threshold: float = 0.8,
                                 block_m: int = 128, block_n: int = 128,
-                                capacity: int = 1024,
+                                capacity: int = COMPACT_CAPACITY,
                                 interpret: bool = False):
     """:func:`pair_scores_catalog` with an on-device survivor-compaction
     epilogue: instead of a (T, bm, bn) mask the host must ``np.nonzero``,
@@ -385,6 +445,8 @@ def pair_scores_catalog_compact(a, b, catalog, *, threshold: float = 0.8,
     primitives, computed per tile while the scores are still in VMEM.
     Strips arrive by the same double-buffered DMA as the mask variant.
     """
+    from jax.experimental.pallas import tpu as pltpu
+
     from .grouped_mm import pltpu_prefetch
 
     m, d = a.shape
@@ -402,17 +464,20 @@ def pair_scores_catalog_compact(a, b, catalog, *, threshold: float = 0.8,
         grid=(t,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, capacity), lambda i, cat: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, cat: (i, 0)),
+            pl.BlockSpec((1, 1, capacity), lambda i, cat: (i, 0, 0)),
+            pl.BlockSpec((1, 1, _LANES), lambda i, cat: (i, 0, 0)),
         ],
     )
-    return pl.pallas_call(
+    packed, counts = pl.pallas_call(
         functools.partial(_catalog_compact_kernel, threshold=threshold,
                           block_m=block_m, block_n=block_n,
                           capacity=capacity),
-        grid_spec=pltpu_prefetch(grid_spec, num_scalar_prefetch=1,
-                                 scratch_shapes=scratch),
-        out_shape=(jax.ShapeDtypeStruct((t, capacity), jnp.int32),
-                   jax.ShapeDtypeStruct((t, 1), jnp.int32)),
+        grid_spec=pltpu_prefetch(
+            grid_spec, num_scalar_prefetch=1,
+            scratch_shapes=scratch + [pltpu.VMEM((block_m, block_n),
+                                                 jnp.int32)]),
+        out_shape=(jax.ShapeDtypeStruct((t, 1, capacity), jnp.int32),
+                   jax.ShapeDtypeStruct((t, 1, _LANES), jnp.int32)),
         interpret=interpret,
     )(catalog, a_p, b_p)
+    return packed.reshape(t, capacity), counts[:, 0, :1]

@@ -9,6 +9,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .pair_sim import COMPACT_CAPACITY, SCORE_PRECISION
+
 __all__ = ["pair_scores_ref", "pair_scores_catalog_ref",
            "pair_scores_catalog_raw_ref", "pair_scores_catalog_compact_ref",
            "pack_survivor_mask",
@@ -17,7 +19,8 @@ __all__ = ["pair_scores_ref", "pair_scores_catalog_ref",
 
 def pair_scores_ref(a, b, *, threshold: float = 0.8, triangular: bool = False):
     """(M, d) × (N, d) → thresholded score matrix (M, N)."""
-    s = jnp.einsum("md,nd->mn", a, b, preferred_element_type=jnp.float32)
+    s = jnp.einsum("md,nd->mn", a, b, precision=SCORE_PRECISION,
+                   preferred_element_type=jnp.float32)
     keep = s >= threshold
     if triangular:
         m, n = s.shape
@@ -48,7 +51,7 @@ def pair_scores_catalog_ref(a, b, catalog, *, threshold: float = 0.8,
         ai = jax.lax.dynamic_slice(a_p, (entry[0] * block_m, 0), (block_m, d))
         bi = jax.lax.dynamic_slice(b_p, (entry[1] * block_n, 0), (block_n, d))
         s = jax.lax.dot_general(
-            ai, bi, (((1,), (1,)), ((), ())),
+            ai, bi, (((1,), (1,)), ((), ())), precision=SCORE_PRECISION,
             preferred_element_type=jnp.float32)
         gi = entry[0] * block_m + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         gj = entry[1] * block_n + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -79,7 +82,7 @@ def pair_scores_catalog_raw_ref(a, b, catalog, *, block_m: int = 128,
         ai = jax.lax.dynamic_slice(a_p, (entry[0] * block_m, 0), (block_m, d))
         bi = jax.lax.dynamic_slice(b_p, (entry[1] * block_n, 0), (block_n, d))
         return jax.lax.dot_general(
-            ai, bi, (((1,), (1,)), ((), ())),
+            ai, bi, (((1,), (1,)), ((), ())), precision=SCORE_PRECISION,
             preferred_element_type=jnp.float32)
 
     return jax.vmap(one)(catalog)
@@ -110,7 +113,7 @@ def pack_survivor_mask(masks, capacity: int):
     jax.jit, static_argnames=("threshold", "block_m", "block_n", "capacity"))
 def pair_scores_catalog_compact_ref(a, b, catalog, *, threshold: float = 0.8,
                                     block_m: int = 128, block_n: int = 128,
-                                    capacity: int = 1024):
+                                    capacity: int = COMPACT_CAPACITY):
     """jnp twin of pair_sim.pair_scores_catalog_compact: same
     ``(packed, counts)`` contract — the mask from
     :func:`pair_scores_catalog_ref` packed by

@@ -181,7 +181,7 @@ def _experts_gshard(p, x, w, ids, cfg: ModelConfig):
     return y.reshape(t, d).astype(x.dtype)
 
 
-def _experts_grouped(p, x, w, ids, cfg: ModelConfig, impl: str = "pallas"):
+def _experts_grouped(p, x, w, ids, cfg: ModelConfig, impl: str = "auto"):
     """Sort-by-expert + Pallas grouped GEMM (tile-aligned, drop-free)."""
     from ..kernels import ops
 

@@ -49,11 +49,8 @@ def make_er_mesh(n_data: int, n_model: int = 1) -> Mesh:
                          f"({n_data}, {n_model}) mesh, "
                          f"have {devices.size}")
     grid = devices[:n_data * n_model].reshape(n_data, n_model)
-    try:
-        return Mesh(grid, ("data", TP_AXIS),
-                    axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    except (AttributeError, TypeError):   # older jax: no AxisType/kwarg
-        return Mesh(grid, ("data", TP_AXIS))
+    return Mesh(grid, ("data", TP_AXIS),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def _active_axes() -> Tuple[str, ...]:

@@ -291,6 +291,51 @@ def test_compact_epilogue_parity_and_exact_counts(capacity):
     assert (outs["interpret"] == outs["xla"]).all()
 
 
+def test_compact_default_capacity_one_tile_past_overflow():
+    """At the resolved default capacity (128×128 tiles) exactly one tile
+    — a 50-record block, 1,225 live pairs — overflows: interpret ≡ xla
+    on packed slots and exact counts, and the executor's fallback keeps
+    the match set equal to the mask path."""
+    from repro.kernels.pair_sim import resolve_capacity
+
+    bm = bn = 128
+    capacity = resolve_capacity(bm, bn)
+    sizes = np.asarray([50, 30, 11], np.int64)
+    n = int(sizes.sum())
+    bdm = compute_bdm(np.repeat(np.arange(sizes.size), sizes),
+                      np.zeros(n, np.int64), sizes.size, 1)
+    cat = lower(plan_to_job(plan_pair_range(bdm, 1)), bm, bn)
+    f = _feats(n, 3)
+    mask = np.asarray(ops.pair_scores_catalog(
+        f, f, cat.tiles, threshold=-1.0, block_m=bm, block_n=bn,
+        impl="xla")).astype(bool)
+    flat = mask.reshape(mask.shape[0], -1)
+    counts_want = flat.sum(axis=1)
+    assert (counts_want > capacity).sum() == 1
+    outs = {}
+    for impl in ("interpret", "xla"):
+        packed, counts = ops.pair_scores_catalog_compact(
+            f, f, cat.tiles, threshold=-1.0, block_m=bm, block_n=bn,
+            impl=impl)
+        packed = np.asarray(packed)
+        assert packed.shape == (cat.tiles.shape[0], capacity)
+        assert (np.asarray(counts).reshape(-1) == counts_want).all()
+        for t in range(flat.shape[0]):
+            pos = np.flatnonzero(flat[t])
+            k = min(pos.size, capacity)
+            assert (packed[t, :k] == pos[:k]).all()
+            assert (packed[t, k:] == 0).all()
+        outs[impl] = packed
+    assert (outs["interpret"] == outs["xla"]).all()
+
+    kw = dict(threshold=-1.0, impl="xla")
+    want = _pairs(*score_catalog(f, cat, compact=False, **kw))
+    before = dict(stage1_stats)
+    assert _pairs(*score_catalog(f, cat, compact=True, **kw)) == want
+    assert stage1_stats["compact_overflows"] == \
+        before["compact_overflows"] + 1
+
+
 def test_score_catalog_compact_path_equals_mask_path():
     cat, n = _small_catalog([50, 30, 11], r=6)
     f = _feats(n, 1)
@@ -298,12 +343,14 @@ def test_score_catalog_compact_path_equals_mask_path():
     before = dict(stage1_stats)
     want = _pairs(*score_catalog(f, cat, compact=False, **kw))
     assert stage1_stats["nonzero_decodes"] > before["nonzero_decodes"]
+    assert stage1_stats["survivors"] - before["survivors"] == len(want) > 0
 
     before = dict(stage1_stats)
     got = _pairs(*score_catalog(f, cat, compact=True, **kw))
     assert got == want
-    # the default capacity (bm·bn) can never overflow: every chunk took
-    # the packed epilogue, the host nonzero scan never ran
+    assert stage1_stats["survivors"] - before["survivors"] == len(got)
+    # at 16×16 the default capacity is bm·bn, which can never overflow:
+    # every chunk took the packed epilogue, the host nonzero scan never ran
     assert stage1_stats["compact_decodes"] > before["compact_decodes"]
     assert stage1_stats["nonzero_decodes"] == before["nonzero_decodes"]
     assert stage1_stats["compact_overflows"] == before["compact_overflows"]

@@ -23,11 +23,8 @@ SCRIPT = textwrap.dedent("""
     from repro.er.executor import build_catalog, verify_pairs
     from repro.er.pipeline import run_er, ERConfig
 
-    try:  # axis_types appeared in newer jax; default is fine where absent
-        mesh = jax.make_mesh((8,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except AttributeError:
-        mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     n_dev = 8
 
     ds = make_products(1024, seed=5)

@@ -20,11 +20,8 @@ SCRIPT = textwrap.dedent("""
                                       plan_tiles_for_devices)
     from repro.er.executor import RED, catalog_for_two_source, verify_pairs
 
-    try:
-        mesh = jax.make_mesh((8,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except AttributeError:
-        mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
     ds = make_products(600, seed=5)
     corpus = ds.titles[:500] + [""]

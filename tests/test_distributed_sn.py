@@ -24,11 +24,8 @@ SCRIPT = textwrap.dedent("""
     from repro.er.distributed import match_sn_dist, sn_replication_volume
     from repro.er.executor import verify_pairs
 
-    try:
-        mesh = jax.make_mesh((8,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except AttributeError:
-        mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     n_dev = 8
     W, DIM, MAXLEN = 64, 128, 48
 

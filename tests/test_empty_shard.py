@@ -21,11 +21,8 @@ SCRIPT = textwrap.dedent("""
     from repro.er.compiler import (execute, lower, plan_to_job,
                                    schedule_tiles, tiles_for_devices)
 
-    try:
-        mesh = jax.make_mesh((8,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except AttributeError:
-        mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     n_dev = 8
 
     # Extreme skew: one dominant block, a couple of tiny ones. With

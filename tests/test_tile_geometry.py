@@ -24,7 +24,7 @@ from repro.er.compiler import (GEOMETRY_LATTICE, GeometryCostModel,
                                enumerate_catalog_pairs, execute, lower,
                                plan_to_job, score_catalog, stage1_stats)
 from repro.kernels.pair_sim import (VMEM_BUDGET_BYTES, catalog_vmem_bytes,
-                                    check_vmem)
+                                    check_vmem, resolve_capacity)
 
 N, D, R, M = 220, 32, 5, 4
 THRESHOLD = 0.3
@@ -114,19 +114,46 @@ def test_occupancy_waste_equals_enumerated_dead_cells(geom, name):
 
 
 def test_every_lattice_candidate_fits_vmem_double_buffered():
-    """Mask path and bounded-capacity compact path fit the budget for
-    every lattice candidate at d=256; unbounded capacity on the largest
-    tiles legitimately does not (the lowering guard catches it)."""
+    """Mask path and compact path at the resolved default capacity fit
+    the budget for every lattice candidate at d=256; one slot per cell
+    on the largest tiles legitimately does not (the lowering guard
+    catches it)."""
     for bm, bn in GEOMETRY_LATTICE:
         assert catalog_vmem_bytes(bm, bn, 256) <= VMEM_BUDGET_BYTES, (bm, bn)
-        check_vmem(bm, bn, 256, capacity=1024)  # shipped serving capacity
-    assert catalog_vmem_bytes(64, 256, 256, capacity=64 * 256) \
+        check_vmem(bm, bn, 256, capacity=resolve_capacity(bm, bn))
+    assert catalog_vmem_bytes(256, 256, 256, capacity=256 * 256) \
         > VMEM_BUDGET_BYTES
 
 
 def test_check_vmem_rejects_oversized_working_set():
     with pytest.raises(ValueError, match="VMEM"):
         check_vmem(1024, 1024, 4096)
+
+
+@pytest.mark.parametrize("geom,asked,resolved", [
+    ((128, 128), None, 1024), ((16, 16), None, 256),
+    ((32, 32), 12_288, 1024), ((64, 64), 64, 64)])
+def test_capacity_resolves_within_the_tile(geom, asked, resolved):
+    """The executor, the mesh scorer and autotune all run the compact
+    kernel at resolve_capacity: the default (or asked) slots, never more
+    than the tile's cells — check_vmem refuses more, outside its fit."""
+    assert resolve_capacity(*geom, asked) == resolved
+    check_vmem(*geom, 256, capacity=resolved)
+    with pytest.raises(ValueError, match="cells"):
+        check_vmem(*geom, 256, capacity=geom[0] * geom[1] + 1)
+
+
+def test_autotune_keeps_only_geometries_both_kernels_fit():
+    """autotune judges the capacity the kernel will run with: at the
+    default every lattice entry fits at d=256, and a d that only the mask
+    kernel fits drops the candidate all the same."""
+    job = _JOBS["block_split"]
+    rep = autotune(job, d=256)
+    assert {s.geometry for s in rep.scores} == set(GEOMETRY_LATTICE)
+    d = 1_400   # 256×256: mask fits, compact at 1,024 slots does not
+    assert catalog_vmem_bytes(256, 256, d) <= VMEM_BUDGET_BYTES \
+        < catalog_vmem_bytes(256, 256, d, resolve_capacity(256, 256))
+    assert (256, 256) not in {s.geometry for s in autotune(job, d=d).scores}
 
 
 def test_autotune_raises_when_nothing_fits():
@@ -226,11 +253,8 @@ def test_service_warm_start_skips_sweep():
 def test_mesh_compact_path_decodes_on_device():
     """The mesh execution path decodes stage-1 survivors from the packed
     epilogue — compact_decodes increments, nonzero_decodes does not."""
-    try:
-        mesh = jax.make_mesh((1,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except AttributeError:
-        mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     cat = lower(_JOBS["block_split"], 64, 64)
     before = dict(stage1_stats)
     ra, rb = execute(cat, FEATS, threshold=THRESHOLD, impl="xla",
