@@ -23,8 +23,8 @@ for strategy in ("basic", "block_split", "pair_range"):
     loads = res.reducer_pairs
     print(f"{strategy:12s} pairs={res.total_pairs:>9,} "
           f"matches={len(res.matches):>5} recall={recall:.3f} "
-          f"max/mean load={loads.max() / max(loads.mean(), 1):>6.2f} "
-          f"modeled-makespan={res.makespan_seconds:.2f}s "
+          f"reducer imbalance (max/mean planned pairs)="
+          f"{loads.max() / max(loads.mean(), 1):>6.2f} "
           f"map-output={res.map_output_size}")
 
 print("\nthe point: one block holds ~70% of all pairs — Basic pins it to a "

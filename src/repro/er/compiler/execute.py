@@ -30,6 +30,12 @@ catalog-predicate epilogue (which is meaningless on partials). Every
 gather/hop/psum's bytes-received-per-device land in
 ``stage1_stats["interconnect"]``.
 
+Both stages bracket their host work in spans (``er/trace.py``): per
+stage 1, ``er.stage1.upload``; per stage-1 chunk, ``er.stage1.launch``
+(tiles, padded), ``er.stage1.sync`` and ``er.stage1.decode``
+(survivors); per stage-2 chunk, ``er.stage2.gather`` (pairs) and
+``er.stage2.sync``.
+
 ``make_scorer`` builds the jitted per-shard scorer ONCE — resident
 services hold one and reuse it for every micro-batch (jit caches by
 function identity, so a per-call closure would retrace every batch).
@@ -47,11 +53,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ...kernels.ops import resolve_impl
 from ...kernels.pair_sim import resolve_capacity
+from ..trace import span
 from .comms import (COMMS_POLICIES, CommsPlan, halo_bytes_per_device,
                     plan_comms, psum_bytes_per_device, rewrite_tiles_local)
 from .faults import DeviceKilledError, FaultInjector, TransientScorerError
 from .feedback import N_TILE_CLASSES, EwmaCostModel, tile_class
-from .ir import A_TILE, B_TILE, NCOLS, TileCatalog
+from .ir import A_TILE, B_TILE, NCOLS, R1, TileCatalog
 from .lower import pad_tiles
 from .schedule import (NoHealthyDevicesError, Schedule, schedule_tiles,
                        tile_costs, tiles_for_devices)
@@ -167,8 +174,9 @@ def score_catalog(feats_a, catalog: TileCatalog, feats_b=None, *,
     impl = resolve_impl(impl)
     if feats_b is None:
         feats_b = feats_a
-    fa = jnp.asarray(feats_a)
-    fb = jnp.asarray(feats_b)
+    with span("stage1.upload"):
+        fa = jnp.asarray(feats_a)
+        fb = jnp.asarray(feats_b)
     tiles = catalog.tiles
     bm, bn = catalog.block_m, catalog.block_n
     t_total = tiles.shape[0]
@@ -177,34 +185,47 @@ def score_catalog(feats_a, catalog: TileCatalog, feats_b=None, *,
     out_a, out_b = [], []
     for lo in range(0, t_total, chunk_tiles):
         chunk = tiles[lo:lo + chunk_tiles]
-        padded = _pad_pow2(chunk.shape[0], chunk_tiles)
-        if padded != chunk.shape[0]:
-            # Empty entries: zero windows (r0 == r1) mask everything out.
-            pad = np.zeros((padded - chunk.shape[0], NCOLS), np.int32)
-            chunk = np.concatenate([chunk, pad], axis=0)
-        chunk_j = jnp.asarray(chunk)
+        live = chunk.shape[0]
+        padded = _pad_pow2(live, chunk_tiles)
+        with span("stage1.launch", tiles=live, padded=padded):
+            if padded != live:
+                # Empty entries: zero windows (r0 == r1) mask everything
+                # out.
+                pad = np.zeros((padded - live, NCOLS), np.int32)
+                chunk = np.concatenate([chunk, pad], axis=0)
+            chunk_j = jnp.asarray(chunk)
+            if use_compact:
+                packed, counts = ops.pair_scores_catalog_compact(
+                    fa, fb, chunk_j, threshold=threshold,
+                    block_m=bm, block_n=bn, capacity=capacity, impl=impl)
         if use_compact:
-            packed, counts = ops.pair_scores_catalog_compact(
-                fa, fb, chunk_j, threshold=threshold,
-                block_m=bm, block_n=bn, capacity=capacity, impl=impl)
-            counts = np.asarray(counts).reshape(-1).astype(np.int64)
-            if counts.max(initial=0) <= capacity:
+            with span("stage1.sync"):
+                counts = np.asarray(counts).reshape(-1).astype(np.int64)
+                fits = counts.max(initial=0) <= capacity
+                if fits:
+                    packed = np.asarray(packed)
+            if fits:
                 stage1_stats["compact_decodes"] += 1
-                ra, rb = _decode_packed(np.asarray(packed), counts,
-                                        chunk, bm, bn)
+                with span("stage1.decode", survivors=int(counts.sum())):
+                    ra, rb = _decode_packed(packed, counts, chunk, bm, bn)
                 out_a.append(ra)
                 out_b.append(rb)
                 continue
             # Exact counts flagged dropped survivors: re-score this
             # chunk through the dense mask (exactness over speed).
             stage1_stats["compact_overflows"] += 1
-        mask = np.asarray(ops.pair_scores_catalog(
-            fa, fb, chunk_j, threshold=threshold,
-            block_m=bm, block_n=bn, impl=impl))
+        with span("stage1.launch", tiles=live, padded=padded):
+            mask = ops.pair_scores_catalog(
+                fa, fb, chunk_j, threshold=threshold,
+                block_m=bm, block_n=bn, impl=impl)
+        with span("stage1.sync"):
+            mask = np.asarray(mask)
         stage1_stats["nonzero_decodes"] += 1
-        ti, ii, jj = np.nonzero(mask)
-        out_a.append(chunk[ti, A_TILE].astype(np.int64) * bm + ii)
-        out_b.append(chunk[ti, B_TILE].astype(np.int64) * bn + jj)
+        with span("stage1.decode") as sp:
+            ti, ii, jj = np.nonzero(mask)
+            sp.set_metadata(survivors=ti.size)
+            out_a.append(chunk[ti, A_TILE].astype(np.int64) * bm + ii)
+            out_b.append(chunk[ti, B_TILE].astype(np.int64) * bn + jj)
     return _survivors(out_a, out_b)
 
 
@@ -461,35 +482,47 @@ def _score_and_compact(shard, operands, tiles_dev, chunk: int,
 
     for lo in range(0, cap, chunk):
         part = tiles_dev[:, lo:lo + chunk]
-        masks = None
+        # Padding entries are all-zero rows; a live tile has r1 > 0.
+        live = int(np.count_nonzero(part[..., R1]))
+        padded = part.shape[0] * part.shape[1]
+        scorer = shard
         if is_compact:
             _account(part.shape[1])
-            packed, counts = shard(*operands, jnp.asarray(part))
-            counts = np.asarray(counts)[..., 0].astype(np.int64)  # (n_dev, C)
-            if counts.max(initial=0) <= shard.capacity:
+            with span("stage1.launch", tiles=live, padded=padded):
+                packed, counts = shard(*operands, jnp.asarray(part))
+            with span("stage1.sync"):
+                counts = np.asarray(counts)[..., 0].astype(np.int64)
+                fits = counts.max(initial=0) <= shard.capacity
+                if fits:
+                    packed = np.asarray(packed)
+            if fits:
                 stage1_stats["compact_decodes"] += 1
-                packed = np.asarray(packed)
-                for dd in range(part.shape[0]):
-                    ra, rb = _decode_packed(packed[dd], counts[dd],
-                                            part[dd], bm, bn)
-                    off_a = base_a[dd] if base_a is not None else 0
-                    off_b = base_b[dd] if base_b is not None else 0
-                    out_a.append(off_a + ra)
-                    out_b.append(off_b + rb)
+                with span("stage1.decode", survivors=int(counts.sum())):
+                    for dd in range(part.shape[0]):
+                        ra, rb = _decode_packed(packed[dd], counts[dd],
+                                                part[dd], bm, bn)
+                        off_a = base_a[dd] if base_a is not None else 0
+                        off_b = base_b[dd] if base_b is not None else 0
+                        out_a.append(off_a + ra)
+                        out_b.append(off_b + rb)
                 continue
             stage1_stats["compact_overflows"] += 1
-            _account(part.shape[1])
-            masks = np.asarray(shard.mask_twin()(*operands,
-                                                 jnp.asarray(part)))
-        if masks is None:
-            _account(part.shape[1])
-            masks = np.asarray(shard(*operands, jnp.asarray(part)))
+            scorer = shard.mask_twin()
+        _account(part.shape[1])
+        with span("stage1.launch", tiles=live, padded=padded):
+            masks = scorer(*operands, jnp.asarray(part))
+        with span("stage1.sync"):
+            masks = np.asarray(masks)
         stage1_stats["nonzero_decodes"] += 1
-        d, ti, ii, jj = np.nonzero(masks)
-        off_a = base_a[d] if base_a is not None else 0
-        off_b = base_b[d] if base_b is not None else 0
-        out_a.append(off_a + part[d, ti, A_TILE].astype(np.int64) * bm + ii)
-        out_b.append(off_b + part[d, ti, B_TILE].astype(np.int64) * bn + jj)
+        with span("stage1.decode") as sp:
+            d, ti, ii, jj = np.nonzero(masks)
+            sp.set_metadata(survivors=d.size)
+            off_a = base_a[d] if base_a is not None else 0
+            off_b = base_b[d] if base_b is not None else 0
+            out_a.append(off_a
+                         + part[d, ti, A_TILE].astype(np.int64) * bm + ii)
+            out_b.append(off_b
+                         + part[d, ti, B_TILE].astype(np.int64) * bn + jj)
     return _survivors(out_a, out_b)
 
 
@@ -647,8 +680,9 @@ def execute(catalog: TileCatalog, feats_a, feats_b=None, *,
                              inter_hops=(plan.inter_hops
                                          if plan is not None else 0),
                              model_axis=model_axis)
-    operands = ((feats_a,) if feats_b is None
-                else (feats_a, jnp.asarray(feats_b)))
+    with span("stage1.upload"):
+        operands = ((feats_a,) if feats_b is None
+                    else (feats_a, jnp.asarray(feats_b)))
     flows = _launch_flows_factory(plan, halo, n_data, n_model, n_rows,
                                   feature_dim, bm, bn)
     return _score_and_compact(scorer, operands, tiles_dev, chunk, bm, bn,
@@ -987,14 +1021,16 @@ def verify_pairs(codes_a, lens_a, codes_b, lens_b, rows_a, rows_b,
 
     hit_a, hit_b = [], []
     for lo in range(0, rows_a.shape[0], chunk):
-        a = rows_a[lo:lo + chunk]
-        b = rows_b[lo:lo + chunk]
-        pad = chunk - a.shape[0]
-        if pad:
-            a = np.concatenate([a, np.zeros(pad, a.dtype)])
-            b = np.concatenate([b, np.zeros(pad, b.dtype)])
-        sim = np.array(edit_similarity(
-            codes_a[a], lens_a[a], codes_b[b], lens_b[b]))
+        with span("stage2.gather", pairs=min(chunk, rows_a.shape[0] - lo)):
+            a = rows_a[lo:lo + chunk]
+            b = rows_b[lo:lo + chunk]
+            pad = chunk - a.shape[0]
+            if pad:
+                a = np.concatenate([a, np.zeros(pad, a.dtype)])
+                b = np.concatenate([b, np.zeros(pad, b.dtype)])
+            operands = codes_a[a], lens_a[a], codes_b[b], lens_b[b]
+        with span("stage2.sync"):
+            sim = np.array(edit_similarity(*operands))
         if pad:
             sim[chunk - pad:] = 0.0
         sel = np.flatnonzero(sim >= threshold)

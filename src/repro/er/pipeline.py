@@ -53,9 +53,10 @@ from ..core.two_source import TwoSourceBDM, plan_pair_range_2src, pairs_of_range
 from .blocking import prefix_block_ids, sn_sort_order
 from .encode import encode_titles, ngram_features
 from .compiler import (apply_schedule, autotune, cross_job,
-                       enumerate_task_pairs, execute_supervised, lower,
-                       match_catalog, plan_to_job, schedule_tiles,
+                       enumerate_task_pairs, execute, execute_supervised,
+                       lower, match_catalog, plan_to_job, schedule_tiles,
                        verify_pairs)
+from .trace import span
 
 __all__ = ["ERConfig", "ERResult", "run_er", "featurize", "cross_restrict"]
 
@@ -129,7 +130,10 @@ class ERResult:
     reducer_pairs: np.ndarray          # (r,) planned pair loads
     map_output_size: int               # kv-pairs emitted by map (Fig. 12)
     bdm_seconds: float                 # Job-1 time (BDM, or the SN sort)
-    reducer_seconds: np.ndarray        # (r,) measured matching time
+    reducer_seconds: np.ndarray        # (r,) matching time: measured per
+                                       # reducer by the reference executor;
+                                       # the catalog executor's measured
+                                       # total split by planned load
     extra: Dict = field(default_factory=dict)
     config: Optional[ERConfig] = None  # the (fresh) config this run used
     schedule: Optional[Dict] = None    # compiler Schedule.stats() (catalog
@@ -139,10 +143,6 @@ class ERResult:
     coverage: float = 1.0              # live pairs scored / planned
     steals: int = 0                    # work-stealing events (supervised)
     measured_makespan_s: float = 0.0   # supervisor busy-time makespan
-
-    @property
-    def makespan_seconds(self) -> float:
-        return float(self.reducer_seconds.max()) if self.reducer_seconds.size else 0.0
 
 
 _VERIFY_CHUNK = 8_192
@@ -262,6 +262,12 @@ def run_er(titles: Sequence[str], config: Optional[ERConfig] = None,
     (padding rows/columns are never referenced by catalog tiles and
     contribute 0 to every dot). The match_⊥ job is query-batch-sized
     and stays on the host path, as does the reference executor.
+
+    Each phase runs in a host span (``er/trace.py``) under
+    ``er.run_er``: ``er.featurize``, ``er.block``, ``er.bdm`` (not for
+    SN), ``er.plan``, then on the catalog executor ``er.job``,
+    ``er.lower``, ``er.schedule``, ``er.stage1``, ``er.stage2`` and
+    ``er.collect``, and ``er.null_key`` when the match_⊥ job runs.
     """
     n = len(titles)
     cfg = config if config is not None else ERConfig()
@@ -279,52 +285,72 @@ def run_er(titles: Sequence[str], config: Optional[ERConfig] = None,
         from .compiler import EwmaCostModel
         feedback = EwmaCostModel(max(cfg.supervised_devices, 1))
 
+    if cfg.strategy not in ("basic", "block_split", "pair_range",
+                            "sorted_neighborhood"):
+        raise ValueError(f"unknown strategy {cfg.strategy!r}")
+    with span("run_er", n=n, strategy=cfg.strategy):
+        return _run_er(titles, n, cfg, block_ids, supervised,
+                       fault_injector, feedback, mesh, axis)
+
+
+def _run_er(titles, n, cfg, block_ids, supervised, fault_injector,
+            feedback, mesh, axis) -> ERResult:
+    """``run_er``'s body, inside its ``er.run_er`` span."""
     # ---- featurize once (shared by both jobs) ----
-    codes, lens, feats = featurize(titles, cfg)
+    with span("featurize", n=n):
+        codes, lens, feats = featurize(titles, cfg)
 
     extra: Dict = {}
     null_idx: Optional[np.ndarray] = None
+    sn = cfg.strategy == "sorted_neighborhood"
 
-    # ---- Job 1 + plan: the ONLY strategy-aware stage ----
-    if cfg.strategy == "sorted_neighborhood":
+    # ---- Job 1: the ONLY strategy-aware stage ----
+    if sn:
         # Job 1 is the sort (no BDM — the band's pair count is a pure
         # function of (n, w), so there is no block skew to measure), and
         # every entity has a sort key, so SN has no match_⊥ job.
-        t0 = time.perf_counter()
-        to_global = sn_sort_order(titles)
-        plan = plan_sorted_neighborhood(n, cfg.window, cfg.r)
-        bdm_seconds = time.perf_counter() - t0
-        map_out = sn_map_output_size(plan)
-        extra.update(window=cfg.window, w_eff=plan.w_eff)
-    elif cfg.strategy in ("basic", "block_split", "pair_range"):
-        if block_ids is None:
-            block_ids, _ = prefix_block_ids(titles, k=cfg.prefix_len)
-        block_ids = np.asarray(block_ids, np.int64)
+        with span("block"):
+            t0 = time.perf_counter()
+            to_global = sn_sort_order(titles)
+            bdm_seconds = time.perf_counter() - t0
+    else:
+        with span("block"):
+            if block_ids is None:
+                block_ids, _ = prefix_block_ids(titles, k=cfg.prefix_len)
+            block_ids = np.asarray(block_ids, np.int64)
 
-        # Input partitions: m contiguous row ranges (HDFS-split analog).
-        part_ids = np.minimum(
-            np.arange(n, dtype=np.int64) * cfg.m // max(n, 1), cfg.m - 1)
+            # Input partitions: m contiguous row ranges (HDFS-split
+            # analog).
+            part_ids = np.minimum(
+                np.arange(n, dtype=np.int64) * cfg.m // max(n, 1), cfg.m - 1)
 
-        keyed = block_ids >= 0
-        keyed_idx = np.flatnonzero(keyed)
-        if (~keyed).any():
-            null_idx = np.flatnonzero(~keyed)
+            keyed = block_ids >= 0
+            keyed_idx = np.flatnonzero(keyed)
+            if (~keyed).any():
+                null_idx = np.flatnonzero(~keyed)
 
-        # ---- Job 1: BDM ----
-        t0 = time.perf_counter()
-        kb = block_ids[keyed_idx]
-        kp = part_ids[keyed_idx]
-        num_blocks = int(kb.max()) + 1 if kb.size else 0
-        bdm = compute_bdm(kb, kp, num_blocks, cfg.m)
-        eidx = entity_indices(kb, kp, bdm)
-        bdm_seconds = time.perf_counter() - t0
+        with span("bdm") as sp:
+            t0 = time.perf_counter()
+            kb = block_ids[keyed_idx]
+            kp = part_ids[keyed_idx]
+            num_blocks = int(kb.max()) + 1 if kb.size else 0
+            sp.set_metadata(blocks=num_blocks)
+            bdm = compute_bdm(kb, kp, num_blocks, cfg.m)
+            eidx = entity_indices(kb, kp, bdm)
+            bdm_seconds = time.perf_counter() - t0
 
-        sizes = bdm.sum(axis=1)
-        perm, _ = blocked_layout(kb, eidx, sizes)
-        # perm[blocked_row] = row within keyed_idx → global entity ids.
-        to_global = keyed_idx[perm]
+            sizes = bdm.sum(axis=1)
+            perm, _ = blocked_layout(kb, eidx, sizes)
+            # perm[blocked_row] = row within keyed_idx → global entity ids.
+            to_global = keyed_idx[perm]
 
-        if cfg.strategy == "pair_range":
+    # ---- plan, and the features in the plan's row order ----
+    with span("plan", rows=int(to_global.size)):
+        if sn:
+            plan = plan_sorted_neighborhood(n, cfg.window, cfg.r)
+            map_out = sn_map_output_size(plan)
+            extra.update(window=cfg.window, w_eff=plan.w_eff)
+        elif cfg.strategy == "pair_range":
             plan = plan_pair_range(bdm, cfg.r)
             # Closed-form O(r + b) math (core/pair_range.map_output_size)
             # — exact at any scale, so it is ALWAYS computed.
@@ -335,12 +361,9 @@ def run_er(titles: Sequence[str], config: Optional[ERConfig] = None,
         else:
             plan = plan_basic(bdm, cfg.r)
             map_out = plan.map_output_size()
-    else:
-        raise ValueError(f"unknown strategy {cfg.strategy!r}")
-
-    g_feats = feats[to_global]
-    g_codes = codes[to_global]
-    g_lens = lens[to_global]
+        g_feats = feats[to_global]
+        g_codes = codes[to_global]
+        g_lens = lens[to_global]
     reducer_pairs = np.asarray(plan.reducer_pairs, np.int64)
     total = int(plan.total_pairs)
 
@@ -389,58 +412,67 @@ def run_er(titles: Sequence[str], config: Optional[ERConfig] = None,
     if cfg.executor == "catalog":
         # The compiler pipeline: lower the plan to MXU tiles, place tiles
         # by exact live-pair cost (LPT), score them all on the kernel,
-        # verify compacted survivors. Wall time is attributed to reducers
-        # by planned load (the paper's balance metric), since no
-        # per-reducer loop exists anymore.
-        job = plan_to_job(plan)
-        catalog = lower(job, *_geometry(job))
+        # verify compacted survivors. No per-reducer loop exists anymore:
+        # ``reducer_seconds`` splits the two stages' measured time by
+        # planned load, so only its sum is a clock reading.
+        with span("job"):
+            job = plan_to_job(plan)
+        with span("lower") as sp:
+            catalog = lower(job, *_geometry(job))
+            sp.set_metadata(tiles=catalog.num_tiles)
         extra["catalog_tiles"] = catalog.num_tiles
         exec_feats, model_axis, comms_plan = g_feats, None, None
-        n_dev = 1
-        if mesh is not None:
-            n_dev = int(mesh.shape[axis])
-            n_model = (int(mesh.shape["model"])
-                       if "model" in mesh.axis_names and axis != "model"
-                       else 1)
-            if n_model > 1:
-                model_axis = "model"
-            # Zero-pad rows to shard×tile-aligned length and columns to
-            # model-divisible width: catalog tiles only reference real
-            # rows, and zero feature columns contribute 0 to every dot.
-            mult = n_dev * int(np.lcm(catalog.block_m, catalog.block_n))
-            rows_p = -(-g_feats.shape[0] // mult) * mult
-            cols_p = -(-g_feats.shape[1] // n_model) * n_model
-            if (rows_p, cols_p) != g_feats.shape:
-                exec_feats = np.zeros((rows_p, cols_p), g_feats.dtype)
-                exec_feats[:g_feats.shape[0], :g_feats.shape[1]] = g_feats
-            if cfg.comms != "flat":
-                from .compiler import plan_comms
-                comms_plan = plan_comms(
-                    catalog, rows_p, n_dev, policy=cfg.comms,
-                    n_model=n_model, feature_dim=cols_p, self_join=True)
-                if comms_plan.fallback:
-                    extra["comms_fallback"] = comms_plan.fallback
-        sched = schedule_tiles(catalog, n_dev=n_dev,
-                               policy=cfg.schedule_policy,
-                               comms_plan=comms_plan)
-        sched_report = sched.stats()
+        n_dev = int(mesh.shape[axis]) if mesh is not None else 1
+        with span("schedule", devices=n_dev):
+            if mesh is not None:
+                n_model = (int(mesh.shape["model"])
+                           if "model" in mesh.axis_names and axis != "model"
+                           else 1)
+                if n_model > 1:
+                    model_axis = "model"
+                # Zero-pad rows to shard×tile-aligned length and columns
+                # to model-divisible width: catalog tiles only reference
+                # real rows, and zero feature columns contribute 0 to
+                # every dot.
+                mult = n_dev * int(np.lcm(catalog.block_m, catalog.block_n))
+                rows_p = -(-g_feats.shape[0] // mult) * mult
+                cols_p = -(-g_feats.shape[1] // n_model) * n_model
+                if (rows_p, cols_p) != g_feats.shape:
+                    exec_feats = np.zeros((rows_p, cols_p), g_feats.dtype)
+                    exec_feats[:g_feats.shape[0],
+                               :g_feats.shape[1]] = g_feats
+                if cfg.comms != "flat":
+                    from .compiler import plan_comms
+                    comms_plan = plan_comms(
+                        catalog, rows_p, n_dev, policy=cfg.comms,
+                        n_model=n_model, feature_dim=cols_p,
+                        self_join=True)
+                    if comms_plan.fallback:
+                        extra["comms_fallback"] = comms_plan.fallback
+            sched = schedule_tiles(catalog, n_dev=n_dev,
+                                   policy=cfg.schedule_policy,
+                                   comms_plan=comms_plan)
+            sched_report = sched.stats()
+            scheduled = apply_schedule(catalog, sched)
         t0 = time.perf_counter()
-        if supervised:
-            ca, cb = _supervised_stage1(
-                apply_schedule(catalog, sched), g_feats)
+        with span("stage1", tiles=catalog.num_tiles):
+            if supervised:
+                ca, cb = _supervised_stage1(scheduled, g_feats)
+            else:
+                ca, cb = execute(
+                    scheduled, exec_feats,
+                    threshold=cfg.threshold - cfg.filter_margin,
+                    impl=cfg.kernel_impl, mesh=mesh, axis=axis,
+                    schedule=sched if mesh is not None else None,
+                    model_axis=model_axis,
+                    compact_capacity=cfg.compact_capacity)
+        with span("stage2", pairs=int(ca.size)):
             ha, hb = verify_pairs(g_codes, g_lens, g_codes, g_lens,
                                   ca, cb, cfg.threshold)
-        else:
-            ha, hb = match_catalog(
-                apply_schedule(catalog, sched), exec_feats, g_codes, g_lens,
-                threshold=cfg.threshold, filter_margin=cfg.filter_margin,
-                impl=cfg.kernel_impl, mesh=mesh, axis=axis,
-                schedule=sched if mesh is not None else None,
-                model_axis=model_axis,
-                compact_capacity=cfg.compact_capacity)
         elapsed = time.perf_counter() - t0
-        for a, b in zip(to_global[ha], to_global[hb]):
-            matches.add((min(int(a), int(b)), max(int(a), int(b))))
+        with span("collect", matches=int(ha.size)):
+            for a, b in zip(to_global[ha], to_global[hb]):
+                matches.add((min(int(a), int(b)), max(int(a), int(b))))
         if total:
             reducer_seconds = (elapsed * reducer_pairs.astype(np.float64)
                                / total)
@@ -458,43 +490,45 @@ def run_er(titles: Sequence[str], config: Optional[ERConfig] = None,
 
     # ---- match_⊥(R, R_∅): entities without blocking key vs everyone ----
     if cfg.match_missing_keys and null_idx is not None and null_idx.size:
-        bdm2 = TwoSourceBDM(
-            bdm_r=np.full((1, 1), n, np.int64),
-            bdm_s=np.full((1, 1), null_idx.size, np.int64))
-        plan2 = plan_pair_range_2src(bdm2, cfg.r)
-        extra["null_key_pairs"] = plan2.total_pairs
-        if cfg.executor == "catalog":
-            xjob = cross_job(n, int(null_idx.size), cfg.r)
-            cross = lower(xjob, *_geometry(xjob))
-            if supervised:
-                ca, cb = _supervised_stage1(cross, feats, feats[null_idx])
-                ha, hb = verify_pairs(codes, lens, codes[null_idx],
-                                      lens[null_idx], ca, cb, cfg.threshold)
-            else:
-                ha, hb = match_catalog(
-                    cross, feats, codes, lens,
-                    feats_b=feats[null_idx], codes_b=codes[null_idx],
-                    lens_b=lens[null_idx],
-                    threshold=cfg.threshold, filter_margin=cfg.filter_margin,
-                    impl=cfg.kernel_impl,
-                    compact_capacity=cfg.compact_capacity)
-            for a, b in zip(ha, null_idx[hb]):
-                a, b = int(a), int(b)
-                if a != b:
-                    matches.add((min(a, b), max(a, b)))
-        else:
-            for k in range(cfg.r):
-                _, _, _, rr, rs = pairs_of_range_2src(plan2, k)
-                if rr.size == 0:
-                    continue
-                ha, hb = _match_pairs_chunked(
-                    feats, codes, lens,
-                    rr, null_idx[rs], cfg.threshold, cfg.filter_margin)
-                for a, b in zip(ha, hb):
+        with span("null_key", rows=int(null_idx.size)):
+            bdm2 = TwoSourceBDM(
+                bdm_r=np.full((1, 1), n, np.int64),
+                bdm_s=np.full((1, 1), null_idx.size, np.int64))
+            plan2 = plan_pair_range_2src(bdm2, cfg.r)
+            extra["null_key_pairs"] = plan2.total_pairs
+            if cfg.executor == "catalog":
+                xjob = cross_job(n, int(null_idx.size), cfg.r)
+                cross = lower(xjob, *_geometry(xjob))
+                if supervised:
+                    ca, cb = _supervised_stage1(cross, feats,
+                                                feats[null_idx])
+                    ha, hb = verify_pairs(codes, lens, codes[null_idx],
+                                          lens[null_idx], ca, cb,
+                                          cfg.threshold)
+                else:
+                    ha, hb = match_catalog(
+                        cross, feats, codes, lens,
+                        feats_b=feats[null_idx], codes_b=codes[null_idx],
+                        lens_b=lens[null_idx], threshold=cfg.threshold,
+                        filter_margin=cfg.filter_margin, impl=cfg.kernel_impl,
+                        compact_capacity=cfg.compact_capacity)
+                for a, b in zip(ha, null_idx[hb]):
                     a, b = int(a), int(b)
                     if a != b:
                         matches.add((min(a, b), max(a, b)))
-        total += plan2.total_pairs
+            else:
+                for k in range(cfg.r):
+                    _, _, _, rr, rs = pairs_of_range_2src(plan2, k)
+                    if rr.size == 0:
+                        continue
+                    ha, hb = _match_pairs_chunked(
+                        feats, codes, lens,
+                        rr, null_idx[rs], cfg.threshold, cfg.filter_margin)
+                    for a, b in zip(ha, hb):
+                        a, b = int(a), int(b)
+                        if a != b:
+                            matches.add((min(a, b), max(a, b)))
+            total += plan2.total_pairs
 
     return ERResult(
         matches=matches,
