@@ -28,6 +28,7 @@ from .pair_range import (  # noqa: F401
     pairs_of_range_jnp,
     plan_pair_range,
     range_block_intervals,
+    range_segments,
 )
 from .two_source import (  # noqa: F401
     BlockSplit2Plan,

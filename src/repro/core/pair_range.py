@@ -26,6 +26,7 @@ __all__ = [
     "plan_pair_range",
     "pairs_of_range",
     "pairs_of_range_jnp",
+    "range_segments",
     "range_block_segments",
     "range_block_intervals",
     "entity_range_matrix",
@@ -101,71 +102,102 @@ def pairs_of_range_jnp(sizes, offsets, estart, lo, count: int, total: int):
     return estart[block] + x, estart[block] + y, valid
 
 
-def range_block_segments(plan: PairRangePlan, k: int) -> List[Tuple[int, int, int, int, int]]:
-    """Per-block pair segments of range k: [(block, x_lo, y_lo, x_hi, y_hi)].
+def _segments(plan: PairRangePlan, ks: np.ndarray) -> np.ndarray:
+    """Segment table of the ranges ``ks`` (ascending): see
+    :func:`range_segments`."""
+    lo, hi = plan.bounds[ks, 0], plan.bounds[ks, 1]
+    live = hi > lo
+    ks, lo, hi = ks[live], lo[live], hi[live]
+    if ks.size == 0:
+        return np.zeros((0, 6), np.int64)
+    offsets = plan.offsets
+    # First and last block of each range (the block of pair lo, of hi - 1).
+    b_lo = np.searchsorted(offsets, lo, side="right") - 1
+    b_hi = np.searchsorted(offsets, hi - 1, side="right") - 1
+    # One entry per (range, block) the range touches, by range then block.
+    width = b_hi - b_lo + 1
+    first = np.cumsum(width) - width
+    step = np.arange(int(width.sum()), dtype=np.int64) - np.repeat(first, width)
+    blk = np.repeat(b_lo, width) + step
+    lo, hi, k = (np.repeat(v, width) for v in (lo, hi, ks))
+    npairs = plan.pair_counts[blk]
+    qlo = np.maximum(lo - offsets[blk], 0)
+    qhi = np.minimum(hi - offsets[blk], npairs) - 1
+    keep = (npairs > 0) & (qhi >= qlo)
+    k, blk, qlo, qhi = k[keep], blk[keep], qlo[keep], qhi[keep]
+    n = plan.block_sizes[blk]
+    x_lo, y_lo = en.invert_cell_index(np.concatenate([qlo, qhi]),
+                                      np.concatenate([n, n]))
+    s = k.size
+    return np.stack([k, blk, x_lo[:s], y_lo[:s], x_lo[s:], y_lo[s:]],
+                    axis=1).astype(np.int64, copy=False)
 
-    Range k's pair-index interval [lo, hi) intersected with block ``blk``
-    is a contiguous run of cell indices, i.e. (in the column-major
-    triangular enumeration) the cells from (x_lo, y_lo) through
-    (x_hi, y_hi) inclusive: a prefix-cut first column, full middle
-    columns, a suffix-cut last column. This is the O(1)-per-block
-    description the tile-catalog executor compiles to corner-cut masks —
-    no per-pair materialization. Only blocks with a non-empty segment are
-    returned; coordinates are block-local.
+
+def range_segments(plan: PairRangePlan) -> np.ndarray:
+    """Every range's per-block pair segments as one (S, 6) int64 table.
+
+    Row ``(k, blk, x_lo, y_lo, x_hi, y_hi)``: range k's pair-index
+    interval [lo, hi) intersected with block ``blk`` is a contiguous run
+    of cell indices, i.e. (in the column-major triangular enumeration)
+    the cells from (x_lo, y_lo) through (x_hi, y_hi) inclusive: a
+    prefix-cut first column, full middle columns, a suffix-cut last
+    column. This is the O(1)-per-block description the tile-catalog
+    executor compiles to corner-cut masks — no per-pair
+    materialization. Rows run by range, then by block; only non-empty
+    segments appear (empty ranges and zero-pair blocks have none);
+    coordinates are block-local. Array operations over the O(r + b)
+    segments: no per-range or per-block Python step.
     """
-    lo, hi = map(int, plan.bounds[k])
-    if hi <= lo:
-        return []
-    sizes, offsets = plan.block_sizes, plan.offsets
-    b_lo, _, _ = en.invert_pair_index(np.int64(lo), sizes, offsets)
-    b_hi, _, _ = en.invert_pair_index(np.int64(hi - 1), sizes, offsets)
-    out = []
-    for blk in range(int(b_lo), int(b_hi) + 1):
-        n = int(sizes[blk])
-        npairs = int(plan.pair_counts[blk])
-        if npairs == 0:
-            continue
-        qlo = max(lo - int(offsets[blk]), 0)
-        qhi = min(hi - int(offsets[blk]), npairs) - 1
-        if qhi < qlo:
-            continue
-        x_lo, y_lo = (int(v) for v in en.invert_cell_index(np.int64(qlo), n))
-        x_hi, y_hi = (int(v) for v in en.invert_cell_index(np.int64(qhi), n))
-        out.append((blk, x_lo, y_lo, x_hi, y_hi))
-    return out
+    return _segments(plan, np.arange(plan.r, dtype=np.int64))
+
+
+def range_block_segments(plan: PairRangePlan, k: int) -> List[Tuple[int, int, int, int, int]]:
+    """Range k's rows of :func:`range_segments`, as
+    [(block, x_lo, y_lo, x_hi, y_hi)] tuples of ints."""
+    segs = _segments(plan, np.array([k], np.int64))
+    return [tuple(row) for row in segs[:, 1:].tolist()]
+
+
+def _gather_intervals(plan: PairRangePlan, segs: np.ndarray):
+    """Block-local gather intervals of each segment of ``segs``: the
+    first ``(lo1, hi1)``, and ``(lo2, hi2)`` where ``two`` holds.
+
+    The <= 2 bound: within one block a contiguous pair-index interval
+    covers columns x_lo..x_hi; if it spans >= 3 columns, some middle
+    column is complete, whose y-values reach N-1, collapsing the union
+    to a single interval [x_lo, N-1]; otherwise the union is
+    [x_lo, ...] plus at most one y-tail, which merges into the first
+    interval when they touch.
+    """
+    _, blk, x_lo, y_lo, x_hi, y_hi = segs.T
+    n = plan.block_sizes[blk]
+    one_col = x_hi == x_lo
+    two_col = x_hi == x_lo + 1
+    # One column: [x_lo] ∪ [y_lo, y_hi], one interval if y_lo = x_lo + 1.
+    # Two columns: [x_lo, x_lo+1] ∪ [x_hi+1, y_hi] = [x_lo, y_hi], plus the
+    # first column's y-tail [y_lo, n-1] unless it touches the first.
+    one_tail = one_col & (y_lo != x_lo + 1)
+    two_tail = two_col & (y_lo > y_hi + 1)
+    hi1 = np.where(one_col, np.where(one_tail, x_lo, y_hi),
+                   np.where(two_tail, y_hi, n - 1))
+    two = one_tail | two_tail
+    hi2 = np.where(one_col, y_hi, n - 1)
+    return x_lo, hi1, y_lo, hi2, two
 
 
 def range_block_intervals(plan: PairRangePlan, k: int) -> List[Tuple[int, List[Tuple[int, int]]]]:
     """Per-block gather intervals (<= 2 each) for range k.
 
     Returns [(block, [(row_lo, row_hi_inclusive), ...]), ...] in blocked-
-    layout rows. Proof sketch of the <=2 bound: within one block a
-    contiguous pair-index interval covers columns x_lo..x_hi; if it spans
-    >= 3 columns, some middle column is complete, whose y-values reach
-    N-1, collapsing the union to a single interval [x_lo, N-1]; otherwise
-    the union is [x_lo, ...] plus at most one y-tail.
+    layout rows; :func:`_gather_intervals` gives the bound's proof.
     """
-    sizes, estart = plan.block_sizes, plan.estart
-    out = []
-    for blk, x_lo, y_lo, x_hi, y_hi in range_block_segments(plan, k):
-        n = int(sizes[blk])
-        if x_hi >= x_lo + 2:
-            ivs = [(x_lo, n - 1)]
-        elif x_hi == x_lo:
-            if y_lo == x_lo + 1:
-                ivs = [(x_lo, y_hi)]
-            else:
-                ivs = [(x_lo, x_lo), (y_lo, y_hi)]
-        else:  # x_hi == x_lo + 1
-            first = (x_lo, y_hi)          # [x_lo, x_lo+1] ∪ [x_hi+1, y_hi]
-            second = (y_lo, n - 1)        # y-tail of the partial first column
-            if second[0] <= first[1] + 1:
-                ivs = [(x_lo, n - 1)]
-            else:
-                ivs = [first, second]
-        base = int(estart[blk])
-        out.append((blk, [(base + a, base + b) for a, b in ivs]))
-    return out
+    segs = _segments(plan, np.array([k], np.int64))
+    base = plan.estart[segs[:, 1]]
+    lo1, hi1, lo2, hi2, two = _gather_intervals(plan, segs)
+    rows = np.stack([segs[:, 1], base + lo1, base + hi1, base + lo2,
+                     base + hi2, two], axis=1).tolist()
+    return [(blk, [(a1, b1), (a2, b2)] if t else [(a1, b1)])
+            for blk, a1, b1, a2, b2, t in rows]
 
 
 def entity_range_matrix(plan: PairRangePlan, max_pairs: int = 50_000_000) -> np.ndarray:
@@ -187,15 +219,16 @@ def entity_range_matrix(plan: PairRangePlan, max_pairs: int = 50_000_000) -> np.
     return mask
 
 
-def map_output_size(plan: PairRangePlan) -> int:
+def map_output_size(plan: PairRangePlan, segs: np.ndarray | None = None) -> int:
     """kv-pairs emitted by map (Fig. 12): sum over entities of the number
     of relevant ranges, equivalently sum over ranges of the gather-set
     size. Closed form via the <=2-interval bound of
-    :func:`range_block_intervals` — O(r + b) work, never O(P), so it is
-    exact at any scale (DS2's 6.7·10⁹ pairs included).
-    ``entity_range_matrix`` remains the brute-force oracle in tests."""
-    total = 0
-    for k in range(plan.r):
-        for _, ivs in range_block_intervals(plan, k):
-            total += sum(hi - lo + 1 for lo, hi in ivs)
-    return total
+    :func:`_gather_intervals`: array operations over the O(r + b)
+    segments of :func:`range_segments` (``segs``, when the caller has
+    the table already), never O(P), so it is exact at any scale (DS2's
+    6.7·10⁹ pairs included). ``entity_range_matrix`` remains the
+    brute-force oracle in tests."""
+    if segs is None:
+        segs = range_segments(plan)
+    lo1, hi1, lo2, hi2, two = _gather_intervals(plan, segs)
+    return int((hi1 - lo1 + 1).sum() + np.where(two, hi2 - lo2 + 1, 0).sum())

@@ -32,7 +32,7 @@ import numpy as np
 
 from ...core.basic import BasicPlan
 from ...core.block_split import BlockSplitPlan
-from ...core.pair_range import PairRangePlan, range_block_segments
+from ...core.pair_range import PairRangePlan, range_segments
 from ...core.sorted_neighborhood import (SortedNeighborhoodPlan,
                                          band_range_segment)
 from ...core.two_source import (BlockSplit2Plan, PairRange2Plan,
@@ -109,7 +109,7 @@ def task_row(a0, alen, b0, blen, tri, red,
 
 
 def make_job(rows, n_rows_a, n_rows_b, r, total, self_join=True) -> MatchJob:
-    tasks = (np.asarray(rows, np.int64) if rows
+    tasks = (np.asarray(rows, np.int64) if len(rows)
              else np.zeros((0, TASK_NCOLS), np.int64))
     return MatchJob(tasks=tasks, n_rows_a=int(n_rows_a),
                     n_rows_b=int(n_rows_b), r=int(r),
@@ -147,20 +147,19 @@ def _job_block_split(plan: BlockSplitPlan) -> MatchJob:
 
 def _job_pair_range(plan: PairRangePlan) -> MatchJob:
     """Range k ∩ block = a corner-cut triangle segment (x_lo..x_hi columns,
-    prefix/suffix cuts at (x_lo, y_lo) / (x_hi, y_hi)) — O(1) scalars per
-    (range, block)."""
-    rows = []
-    for k in range(plan.r):
-        for blk, x_lo, y_lo, x_hi, y_hi in range_block_segments(plan, k):
-            e0 = int(plan.estart[blk])
-            n = int(plan.block_sizes[blk])
-            c0 = e0 + (y_lo if x_hi == x_lo else x_lo + 1)
-            c1 = e0 + (y_hi + 1 if x_hi == x_lo else n)
-            rows.append(task_row(
-                e0 + x_lo, x_hi - x_lo + 1, c0, c1 - c0, True, k,
-                lb=(e0 + x_lo, e0 + y_lo), ub=(e0 + x_hi, e0 + y_hi)))
+    prefix/suffix cuts at (x_lo, y_lo) / (x_hi, y_hi)) — one task per row
+    of :func:`range_segments`, built column-wise."""
+    k, blk, x_lo, y_lo, x_hi, y_hi = range_segments(plan).T
+    e0 = plan.estart[blk]
+    one_col = x_hi == x_lo
+    c0 = e0 + np.where(one_col, y_lo, x_lo + 1)
+    c1 = e0 + np.where(one_col, y_hi + 1, plan.block_sizes[blk])
+    tasks = np.stack([
+        e0 + x_lo, x_hi - x_lo + 1, c0, c1 - c0, np.ones_like(k),
+        e0 + x_lo, e0 + y_lo, e0 + x_hi, e0 + y_hi, np.zeros_like(k), k,
+    ], axis=1)
     n_rows = int(plan.block_sizes.sum())
-    return make_job(rows, n_rows, n_rows, plan.r, plan.total_pairs)
+    return make_job(tasks, n_rows, n_rows, plan.r, plan.total_pairs)
 
 
 def _job_sorted_neighborhood(plan: SortedNeighborhoodPlan) -> MatchJob:

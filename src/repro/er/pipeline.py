@@ -42,7 +42,8 @@ from ..core import (
 )
 from ..core.basic import BasicPlan
 from ..core.block_split import BlockSplitPlan
-from ..core.pair_range import PairRangePlan, map_output_size as pair_range_map_output_size
+from ..core.pair_range import (PairRangePlan, range_segments,
+                               map_output_size as pair_range_map_output_size)
 from ..core.sorted_neighborhood import (
     SortedNeighborhoodPlan,
     map_output_size as sn_map_output_size,
@@ -345,16 +346,19 @@ def _run_er(titles, n, cfg, block_ids, supervised, fault_injector,
             to_global = keyed_idx[perm]
 
     # ---- plan, and the features in the plan's row order ----
-    with span("plan", rows=int(to_global.size)):
+    with span("plan", rows=int(to_global.size)) as sp:
         if sn:
             plan = plan_sorted_neighborhood(n, cfg.window, cfg.r)
             map_out = sn_map_output_size(plan)
             extra.update(window=cfg.window, w_eff=plan.w_eff)
         elif cfg.strategy == "pair_range":
             plan = plan_pair_range(bdm, cfg.r)
-            # Closed-form O(r + b) math (core/pair_range.map_output_size)
-            # — exact at any scale, so it is ALWAYS computed.
-            map_out = pair_range_map_output_size(plan)
+            # Closed form (core/pair_range.map_output_size): array
+            # operations over the O(r + b) (range, block) segments —
+            # exact at any scale, so it is ALWAYS computed.
+            segs = range_segments(plan)
+            sp.set_metadata(segments=int(segs.shape[0]))
+            map_out = pair_range_map_output_size(plan, segs)
         elif cfg.strategy == "block_split":
             plan = plan_block_split(bdm, cfg.r)
             map_out = plan.map_output_size()
@@ -415,8 +419,9 @@ def _run_er(titles, n, cfg, block_ids, supervised, fault_injector,
         # verify compacted survivors. No per-reducer loop exists anymore:
         # ``reducer_seconds`` splits the two stages' measured time by
         # planned load, so only its sum is a clock reading.
-        with span("job"):
+        with span("job") as sp:
             job = plan_to_job(plan)
+            sp.set_metadata(tasks=job.num_tasks)
         with span("lower") as sp:
             catalog = lower(job, *_geometry(job))
             sp.set_metadata(tiles=catalog.num_tiles)

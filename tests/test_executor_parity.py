@@ -8,6 +8,7 @@ import pytest
 
 from repro.core import (compute_bdm, plan_basic, plan_block_split,
                         plan_pair_range, pairs_of_range)
+from pair_range_oracle import EDGE_CASES
 from repro.core.pair_range import entity_range_matrix, map_output_size
 from repro.er import ERConfig, make_products, run_er
 from repro.er.blocking import exponential_block_ids
@@ -117,11 +118,19 @@ def test_cross_catalog_two_source():
                                                          wb.tolist()))
 
 
-def test_map_output_size_closed_form_equals_bruteforce():
-    """The O(r + b) map_output_size equals the brute-force per-pair oracle
-    (and run_er no longer emits the -1 sentinel)."""
+def _random_bdms():
     rng = np.random.default_rng(5)
     for _ in range(25):
         bdm = rng.integers(0, 25, (rng.integers(1, 10), rng.integers(1, 4)))
-        plan = plan_pair_range(bdm, int(rng.integers(1, 7)))
+        yield bdm, int(rng.integers(1, 7))
+
+
+@pytest.mark.parametrize("case", ["random"] + sorted(EDGE_CASES))
+def test_map_output_size_closed_form_equals_bruteforce(case):
+    """The O(r + b) map_output_size equals the brute-force per-pair oracle
+    (and run_er no longer emits the -1 sentinel), on 25 seeded BDMs and
+    on each PairRange edge case."""
+    cases = _random_bdms() if case == "random" else [EDGE_CASES[case]]
+    for bdm, r in cases:
+        plan = plan_pair_range(bdm, r)
         assert map_output_size(plan) == int(entity_range_matrix(plan).sum())
