@@ -45,14 +45,10 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                                + str(n_data * n_model))
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import numpy as np, jax.numpy as jnp
     from dataclasses import replace
     from repro.er import ERConfig, run_er
     from repro.er.datasets import make_products
-    from repro.er.blocking import sn_sort_order
-    from repro.er.encode import encode_titles, ngram_features
-    from repro.er.distributed import match_sn_dist, sn_replication_volume
-    from repro.er.executor import verify_pairs
+    from repro.er.distributed import sn_replication_volume
     from repro.er.compiler.execute import stage1_stats
     from repro.sharding import make_er_mesh
 
@@ -93,30 +89,20 @@ SCRIPT = textwrap.dedent("""
     sn_titles = titles[:n_sn]
     n_loc = n_sn // n_data
     W = n_loc + max(n_loc // 4, 2)        # 2 chained hops
-    sn_host = run_er(sn_titles, replace(
-        cfg, strategy="sorted_neighborhood", window=W, r=n_data))
-    order = sn_sort_order(sn_titles)
-    codes, lens = encode_titles(sn_titles, cfg.max_len)
-    feats = ngram_features(codes, dim=cfg.feature_dim, lengths=lens)
+    sn_cfg = replace(cfg, strategy="sorted_neighborhood", window=W,
+                     r=n_data)
+    sn_host = run_er(sn_titles, sn_cfg)
     before = snap()
-    ca, cb = match_sn_dist(jnp.asarray(feats[order]), W, mesh,
-                           threshold=cfg.threshold - cfg.filter_margin)
-    halo_recv = stage1_stats["interconnect"]["halo_bytes"] \\
-        - before["halo_bytes"]
-    ha, hb = verify_pairs(codes[order], lens[order], codes[order],
-                          lens[order], ca, cb, cfg.threshold)
-    got = set()
-    for a, b in zip(ha, hb):
-        ga, gb = int(order[a]), int(order[b])
-        got.add((min(ga, gb), max(ga, gb)))
+    got = run_er(sn_titles, sn_cfg, mesh=mesh).matches
+    moved = {k: stage1_stats["interconnect"][k] - before[k] for k in FLOWS}
     per_hop = sn_replication_volume(n_sn, W, n_data, cfg.feature_dim,
                                     per_hop=True)
     rows.append({
         "leg": "halo", "policy": "multi-hop",
         "n_data": n_data, "n_model": n_model,
         "matches": len(got), "equal": got == sn_host.matches,
-        "gather_bytes_per_dev": halo_recv,
-        "psum_bytes_per_dev": 0,
+        "gather_bytes_per_dev": moved["halo_bytes"],
+        "psum_bytes_per_dev": moved["psum_bytes"],
         "hops": len(per_hop),
         "hop_bytes_ok": sum(per_hop) == (W - 1) * cfg.feature_dim * 4,
         "wall_s": None,

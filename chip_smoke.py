@@ -13,9 +13,9 @@ exactly with the reference restricted to cross pairs, with zero
 compiles after warmup. The stage-1 program must hold the Pallas kernel.
 
 Four chips: the same corpus through ``run_er`` on a 4-device data mesh
-with the flat and ring gathers, the RepSN halo executor
-(``match_sn_dist``: single-hop at w = 10 over DS1, multi-hop on an
-8,192-title slice) and the sharded-index ``ERService``, each compared
+with the flat and ring gathers, Sorted Neighborhood through the same
+``run_er`` (the RepSN halo: single-hop at w = 10 over DS1, multi-hop on
+an 8,192-title slice) and the sharded-index ``ERService``, each compared
 exactly with the one-chip run of the same job.
 
 Everything runs in this one process, which holds the chip(s). Any
@@ -197,31 +197,23 @@ def serve(ds, cfg, want_all, mesh=None):
 
 
 def sn_halo(ds, impl: str, mesh, n: int, window: int):
-    """match_sn_dist on the mesh vs the one-chip SN run_er."""
-    from repro.er import ERConfig, run_er, sn_sort_order
-    from repro.er.compiler import verify_pairs
-    from repro.er.distributed import match_sn_dist
-    from repro.er.pipeline import featurize
+    """SN through run_er on the mesh (the RepSN halo) vs one chip."""
+    from repro.er import ERConfig, run_er
     n_dev = int(mesh.shape["data"])
-    titles = ds.titles[:n - n % n_dev]
+    titles = ds.titles[:n]
     cfg = ERConfig(strategy="sorted_neighborhood", window=window,
                    r=R_TASKS, kernel_impl=impl)
     want = run_er(titles, cfg).matches
-    order = sn_sort_order(titles)
-    codes, lens, feats = featurize(titles, cfg)
     before = stats_snapshot()
-    ca, cb = match_sn_dist(feats[order], window, mesh,
-                           threshold=cfg.threshold - cfg.filter_margin,
-                           impl=impl)
-    ha, hb = verify_pairs(codes[order], lens[order], codes[order],
-                          lens[order], ca, cb, cfg.threshold)
-    got = {(min(int(order[a]), int(order[b])),
-            max(int(order[a]), int(order[b]))) for a, b in zip(ha, hb)}
-    hops = -(-(window - 1) // (len(titles) // n_dev))
+    got = run_er(titles, cfg, mesh=mesh).matches
+    moved = stats_delta(before)
+    hops = -(-(window - 1) // -(-len(titles) // n_dev))
     log(f"SN halo n={len(titles)} w={window} ({hops} hop(s)): "
         f"{len(got)} matches, one chip {len(want)}, equal={got == want}, "
-        f"stage1_stats {stats_delta(before)}")
+        f"stage1_stats {moved}")
     check(got == want, f"SN halo w={window} differs from one chip")
+    check(moved["interconnect"].get("flat_bytes", 0) == 0,
+          f"SN halo w={window} all-gathered the corpus")
 
 
 def mesh_self_join(ds, cfg, mesh, comms: str, want):

@@ -52,6 +52,7 @@ from .ir import (  # noqa: F401
     cross_job,
     make_job,
     plan_to_job,
+    sn_halo_job,
     task_row,
 )
 from .lower import (  # noqa: F401

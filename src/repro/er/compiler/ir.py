@@ -52,6 +52,7 @@ __all__ = [
     "make_job",
     "TileCatalog",
     "plan_to_job",
+    "sn_halo_job",
     "cross_job",
 ]
 
@@ -180,6 +181,31 @@ def _job_sorted_neighborhood(plan: SortedNeighborhoodPlan) -> MatchJob:
             i_lo, i_hi - i_lo + 1, c0, c1 - c0, True, k,
             lb=(i_lo, j_lo), ub=(i_hi, j_hi), band=we))
     return make_job(rows, n, n, plan.r, plan.total_pairs)
+
+
+def sn_halo_job(plan: SortedNeighborhoodPlan, n_dev: int,
+                n_loc: int) -> MatchJob:
+    """The window-w band split by row shard, for RepSN on a data mesh
+    (features in sorted-key order, ``n_loc`` rows a device).
+
+    Device d owns the band pairs whose smaller sorted position lies in
+    its shard [d·n_loc, (d+1)·n_loc) and reads only the w_eff − 1 rows
+    after it. Its one banded task is in shard-local coordinates over the
+    [local ‖ halo] strip — every predicate compares rows and columns
+    alone or col − row, so it is translation-invariant — with reducer =
+    device. The row window ends at the shard's last real row and the
+    column window at the corpus's, which masks row padding and the
+    halo that wraps round to the last shard."""
+    n, we = plan.n, plan.w_eff
+    rows = []
+    for d in range(n_dev):
+        real = n - d * n_loc
+        c1 = min(real, n_loc + we - 1)
+        if real > 1 and we > 1:
+            rows.append(task_row(0, min(real, n_loc), 1, c1 - 1, True, d,
+                                 band=we))
+    strip = n_loc + max(we - 1, 0)
+    return make_job(rows, strip, strip, n_dev, plan.total_pairs)
 
 
 def _job_two_source(plan) -> MatchJob:

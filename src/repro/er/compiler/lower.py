@@ -49,7 +49,8 @@ def task_tiles(a0: int, alen: int, b0: int, blen: int, tri: bool,
     of a task carries the same scalars; triangular tasks drop tiles
     entirely on/below the diagonal (no row < col cell), banded tasks
     additionally drop tiles entirely above the col − row < band diagonal —
-    the tile set hugs the band instead of filling the bounding rectangle."""
+    the tile set hugs the band instead of filling the bounding rectangle
+    — and list their tiles diagonal by diagonal, nearest first."""
     if alen <= 0 or blen <= 0:
         return np.zeros((0, NCOLS), np.int32)
     ii = np.arange(a0 // bm, -(-(a0 + alen) // bm), dtype=np.int64)
@@ -64,7 +65,12 @@ def task_tiles(a0: int, alen: int, b0: int, blen: int, tri: bool,
         # (col − row) is clipped_col_start − (clipped_row_end − 1).
         keep = (np.maximum(tjj * bn, b0)
                 < np.minimum((tii + 1) * bm, a0 + alen) + band - 1)
-        tii, tjj = tii[keep], tjj[keep]
+        # Diagonal tiles first: they hold the nearest sort neighbours,
+        # the band's densest survivors, so the stage-1 launches whose
+        # tiles overflow the compact capacity (and re-score through the
+        # mask path) are the first ones, not every one.
+        order = np.lexsort((tii[keep], tjj[keep] - tii[keep]))
+        tii, tjj = tii[keep][order], tjj[keep][order]
     t = np.empty((tii.size, NCOLS), np.int32)
     t[:, A_TILE] = tii
     t[:, B_TILE] = tjj

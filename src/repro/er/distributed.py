@@ -15,9 +15,10 @@ Job 2 runs through the unified compiler (``er/compiler``): any plan
 lowers to a tile catalog, the cost-LPT scheduler places tiles on
 reducers and devices, and ``compiler.execute`` scores every shard
 through the fused kernel. The entry points here — ``match_catalog_dist``
-(self-join), ``match_catalog_2src_dist`` (query-vs-corpus) and
-``match_sn_dist`` (RepSN halo exchange) — are thin shims over that one
-executor, kept for their historical signatures. Two genuinely different
+(self-join) and ``match_catalog_2src_dist`` (query-vs-corpus) — are thin
+shims over that one executor, kept for their historical signatures;
+Sorted Neighborhood on a mesh (the RepSN halo exchange) runs through
+``run_er(mesh=...)``. Two genuinely different
 legacy executors remain for comparison benchmarks:
 
   * :func:`match_pair_range_dist` — PairRange fully in-jit: every device
@@ -39,14 +40,13 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..core.pair_range import PairRangePlan, pairs_of_range_jnp
 from ..core.sorted_neighborhood import _w_eff
 from .compiler import (DeviceKilledError, FaultEvent, FaultInjector,
-                       FaultScript, MatchJob, NoHealthyDevicesError,
+                       FaultScript, NoHealthyDevicesError,
                        RecoveryFailedError, SupervisedReport, TileCatalog,
                        TransientScorerError, device_assignment, execute,
-                       execute_supervised, lower, make_scorer, pad_tiles,
+                       execute_supervised, make_scorer, pad_tiles,
                        shard_sane, tiles_for_devices)
 from .compiler.comms import halo_bytes_per_device
 from .compiler.execute import _score_and_compact
-from .compiler.ir import make_job, task_row
 from .similarity import two_stage_match
 
 __all__ = [
@@ -67,7 +67,6 @@ __all__ = [
     "make_catalog_2src_scorer",
     "score_tiles_2src",
     "match_pair_range_dist",
-    "match_sn_dist",
     "match_shards_hostplan",
     "device_assignment",
     "plan_rows_for_devices",
@@ -210,7 +209,8 @@ def sn_replication_volume(n: int, w: int, n_dev: int, feature_dim: int,
                           itemsize: int = 4, per_hop: bool = False):
     """Job-2 interconnect bytes *received* across all devices:
     (boundary replication, full all-gather) — or, with ``per_hop``, the
-    per-device hop-by-hop byte schedule of the multi-hop halo chain.
+    per-device hop-by-hop byte schedule of the multi-hop halo chain that
+    ``run_er`` runs for SN on a mesh (``n`` divisible by ``n_dev``).
 
     RepSN replicates only the w−1 boundary rows between adjacent shards —
     O(n_dev · w · d) — where the generic executors all_gather the whole
@@ -231,55 +231,6 @@ def sn_replication_volume(n: int, w: int, n_dev: int, feature_dim: int,
         return halo_bytes_per_device(n_loc, halo, feature_dim, itemsize)
     return (n_dev * halo * feature_dim * itemsize,
             n_dev * (n - n_loc) * feature_dim * itemsize)
-
-
-def match_sn_dist(feats, w: int, mesh: Mesh, axis: str = "data",
-                  threshold: float = 0.8, impl: str = "xla",
-                  block_m: int = 128, block_n: int = 128,
-                  chunk_tiles: int = 1024) -> Tuple[np.ndarray, np.ndarray]:
-    """Stage 1 of Sorted Neighborhood on a mesh, RepSN-style.
-
-    feats (n, d) f32 in *sorted-key order*, row-sharded over ``axis``
-    (n must divide evenly). Device d owns every band pair whose smaller
-    sorted position falls in its shard, and fetches only the w−1 boundary
-    rows of the *next* shard with a neighbor ``ppermute`` — no all-gather
-    (:func:`sn_replication_volume` accounts the byte gap). The shard's
-    band job is compiled host-side in shard-local coordinates over the
-    concatenated [local ‖ halo] strip (all catalog predicates are
-    translation-invariant comparisons, and the band itself only depends
-    on col − row) as ONE banded task per device with reducer = device —
-    the compiler lowers/routes it like any other MatchJob, and the "halo"
-    executor mode replaces the all-gather; the wrapped halo of the last
-    device is masked out by its task's column window.
-
-    Any window size: when w − 1 > n/n_dev the halo spans several shards
-    and the scorer chains ⌈(w−1)/n_loc⌉ neighbor hops (the last hop
-    forwards only the final partial strip, so each device still receives
-    exactly w−1 rows — ``sn_replication_volume(per_hop=True)`` is the
-    schedule). Returns compacted stage-1 survivor candidates
-    (rows_a, rows_b) as sorted-order host int64 arrays; run stage 2
-    with ``compiler.verify_pairs``.
-    """
-    n, _ = feats.shape
-    n_dev = int(mesh.shape[axis])
-    if n % n_dev:
-        raise ValueError(f"n={n} not divisible by n_dev={n_dev}")
-    n_loc = n // n_dev
-    we = _w_eff(n, w)
-    halo = we - 1
-
-    rows = []
-    for dev in range(n_dev):
-        c1 = min(n - dev * n_loc, n_loc + halo)   # last shard: mask the wrap
-        rows.append(task_row(0, n_loc, 1, c1 - 1, True, dev, band=we))
-    # total_pairs = 0: per-shard band pair counts are owned by the
-    # SortedNeighborhoodPlan; this job is routing geometry only.
-    job: MatchJob = make_job(rows, n_loc + halo, n_loc + halo, n_dev, 0)
-    catalog = lower(job, block_m, block_n)
-    base = np.arange(n_dev, dtype=np.int64) * n_loc
-    return execute(catalog, feats, threshold=threshold, impl=impl,
-                   mesh=mesh, axis=axis, chunk_tiles=chunk_tiles,
-                   halo=halo, base=base)
 
 
 # ---------------------------------------------------------------------------

@@ -55,8 +55,8 @@ from .blocking import prefix_block_ids, sn_sort_order
 from .encode import encode_titles, ngram_features
 from .compiler import (apply_schedule, autotune, cross_job,
                        enumerate_task_pairs, execute, execute_supervised,
-                       lower, match_catalog, plan_to_job, schedule_tiles,
-                       verify_pairs)
+                       halo_hop_rows, lower, match_catalog, plan_to_job,
+                       schedule_tiles, sn_halo_job, verify_pairs)
 from .trace import span
 
 __all__ = ["ERConfig", "ERResult", "run_er", "featurize", "cross_restrict"]
@@ -109,7 +109,10 @@ class ERConfig:
                                        # run_er is given a mesh (plans
                                        # that miss the ring preconditions
                                        # degrade to flat, reported on
-                                       # ERResult.extra["comms_fallback"])
+                                       # ERResult.extra["comms_fallback"]);
+                                       # SN on a mesh always exchanges
+                                       # the w − 1-row halo instead, so
+                                       # it does not apply there
     # ---- fault-tolerant execution (catalog executor only) ----
     supervised_devices: int = 0        # > 0: stage 1 through the supervisor
                                        # on N logical device shards
@@ -256,9 +259,14 @@ def run_er(titles: Sequence[str], config: Optional[ERConfig] = None,
 
     ``mesh`` runs the main Job-2 catalog on real devices (catalog
     executor only) through ``compiler.execute``: rows shard over
-    ``axis``, ``cfg.comms`` picks the gather policy, and — when the mesh
-    has a ``model`` axis of size > 1 (``sharding.make_er_mesh``) — the
-    feature dimension shards over it with in-scorer psum combination.
+    ``axis`` and ``cfg.comms`` picks the gather policy. SN takes
+    RepSN's data flow there instead: each device scores the band pairs
+    whose smaller sorted position lies in its shard
+    (``compiler.sn_halo_job``) and receives only the w − 1 rows after
+    it, over chained neighbour hops; ``ERResult.schedule`` reports that
+    fixed placement. When the mesh has a ``model`` axis of size > 1
+    (``sharding.make_er_mesh``), the feature dimension shards over it
+    with in-scorer psum combination.
     Features are zero-padded to shard/tile-divisible sizes host-side
     (padding rows/columns are never referenced by catalog tiles and
     contribute 0 to every dot). The match_⊥ job is query-batch-sized
@@ -419,34 +427,45 @@ def _run_er(titles, n, cfg, block_ids, supervised, fault_injector,
         # verify compacted survivors. No per-reducer loop exists anymore:
         # ``reducer_seconds`` splits the two stages' measured time by
         # planned load, so only its sum is a clock reading.
+        n_dev = int(mesh.shape[axis]) if mesh is not None else 1
+        halo_sn = sn and mesh is not None
+        n_loc = -(-n // n_dev)
         with span("job") as sp:
-            job = plan_to_job(plan)
+            job = (sn_halo_job(plan, n_dev, n_loc) if halo_sn
+                   else plan_to_job(plan))
             sp.set_metadata(tasks=job.num_tasks)
         with span("lower") as sp:
             catalog = lower(job, *_geometry(job))
             sp.set_metadata(tiles=catalog.num_tiles)
         extra["catalog_tiles"] = catalog.num_tiles
         exec_feats, model_axis, comms_plan = g_feats, None, None
-        n_dev = int(mesh.shape[axis]) if mesh is not None else 1
-        with span("schedule", devices=n_dev):
+        halo, base = 0, None
+        with span("schedule", devices=n_dev) as sp:
             if mesh is not None:
                 n_model = (int(mesh.shape["model"])
                            if "model" in mesh.axis_names and axis != "model"
                            else 1)
                 if n_model > 1:
                     model_axis = "model"
-                # Zero-pad rows to shard×tile-aligned length and columns
-                # to model-divisible width: catalog tiles only reference
-                # real rows, and zero feature columns contribute 0 to
-                # every dot.
-                mult = n_dev * int(np.lcm(catalog.block_m, catalog.block_n))
+                # Zero-pad rows to shard×tile-aligned length (the halo
+                # job's tiles are shard-local: n_loc rows a shard) and
+                # columns to model-divisible width: catalog tiles only
+                # reference real rows, and zero feature columns
+                # contribute 0 to every dot.
+                mult = n_dev * (1 if halo_sn else int(
+                    np.lcm(catalog.block_m, catalog.block_n)))
                 rows_p = -(-g_feats.shape[0] // mult) * mult
                 cols_p = -(-g_feats.shape[1] // n_model) * n_model
                 if (rows_p, cols_p) != g_feats.shape:
                     exec_feats = np.zeros((rows_p, cols_p), g_feats.dtype)
                     exec_feats[:g_feats.shape[0],
                                :g_feats.shape[1]] = g_feats
-                if cfg.comms != "flat":
+                if halo_sn:
+                    halo = plan.w_eff - 1
+                    base = np.arange(n_dev, dtype=np.int64) * n_loc
+                    sp.set_metadata(halo=halo,
+                                    hops=len(halo_hop_rows(n_loc, halo)))
+                elif cfg.comms != "flat":
                     from .compiler import plan_comms
                     comms_plan = plan_comms(
                         catalog, rows_p, n_dev, policy=cfg.comms,
@@ -454,8 +473,11 @@ def _run_er(titles, n, cfg, block_ids, supervised, fault_injector,
                         self_join=True)
                     if comms_plan.fallback:
                         extra["comms_fallback"] = comms_plan.fallback
+            # The halo job's tiles index their own device's strip, so
+            # its placement is its attribution: reducer = device.
             sched = schedule_tiles(catalog, n_dev=n_dev,
-                                   policy=cfg.schedule_policy,
+                                   policy=("round_robin" if halo_sn
+                                           else cfg.schedule_policy),
                                    comms_plan=comms_plan)
             sched_report = sched.stats()
             scheduled = apply_schedule(catalog, sched)
@@ -469,7 +491,7 @@ def _run_er(titles, n, cfg, block_ids, supervised, fault_injector,
                     threshold=cfg.threshold - cfg.filter_margin,
                     impl=cfg.kernel_impl, mesh=mesh, axis=axis,
                     schedule=sched if mesh is not None else None,
-                    model_axis=model_axis,
+                    model_axis=model_axis, halo=halo, base=base,
                     compact_capacity=cfg.compact_capacity)
         with span("stage2", pairs=int(ca.size)):
             ha, hb = verify_pairs(g_codes, g_lens, g_codes, g_lens,

@@ -238,11 +238,10 @@ MULTI_HOP = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax, numpy as np, jax.numpy as jnp
-    from repro.er import make_products, sn_sort_order
-    from repro.er.encode import encode_titles, ngram_features
-    from repro.er.distributed import match_sn_dist, sn_replication_volume
-    from repro.er.executor import verify_pairs
+    import jax, numpy as np
+    from repro.er import ERConfig, make_products, run_er
+    from repro.er.compiler import stage1_stats
+    from repro.er.distributed import sn_replication_volume
     from repro.sharding import make_er_mesh
     from sn_oracle import sn_oracle_matches
 
@@ -252,18 +251,11 @@ MULTI_HOP = textwrap.dedent("""
     titles = ds.titles[:n]
     W = n // n_dev + 37                    # w − 1 > n / n_dev: 2 hops
 
-    order = sn_sort_order(titles)
-    codes, lens = encode_titles(titles, MAXLEN)
-    feats = ngram_features(codes, dim=DIM, lengths=lens)
-    mesh = make_er_mesh(n_dev)
-    ca, cb = match_sn_dist(jnp.asarray(feats[order]), W, mesh,
-                           threshold=0.8 - 0.25)
-    ha, hb = verify_pairs(codes[order], lens[order], codes[order],
-                          lens[order], ca, cb, 0.8)
-    got = set()
-    for a, b in zip(ha, hb):
-        ga, gb = int(order[a]), int(order[b])
-        got.add((min(ga, gb), max(ga, gb)))
+    before = stage1_stats["interconnect"]["halo_bytes"]
+    res = run_er(titles, ERConfig(strategy="sorted_neighborhood", window=W,
+                                  r=n_dev, feature_dim=DIM, max_len=MAXLEN),
+                 mesh=make_er_mesh(n_dev))
+    got = res.matches
     want = sn_oracle_matches(titles, W, feature_dim=DIM, max_len=MAXLEN)
     assert got == want, (len(got), len(want))
     hops = -(-(W - 1) // (n // n_dev))
@@ -271,6 +263,8 @@ MULTI_HOP = textwrap.dedent("""
     per_hop = sn_replication_volume(n, W, n_dev, DIM, per_hop=True)
     assert len(per_hop) == hops
     assert sum(per_hop) == (W - 1) * DIM * 4
+    assert stage1_stats["interconnect"]["halo_bytes"] - before \
+        == sum(per_hop)
     print("multi-hop oracle OK:", len(got), "matches,", hops, "hops")
 """)
 

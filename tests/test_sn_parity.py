@@ -107,3 +107,28 @@ def test_sn_catalog_tiles_hug_the_band():
     n_strips = 4096 // 128
     assert cat.num_tiles <= 3 * n_strips        # ~2 per strip row for w≪bm
     assert cat.num_tiles < n_strips * n_strips / 4
+
+
+@pytest.mark.parametrize("n,w,n_dev", [(300, 17, 4), (301, 90, 4),
+                                       (130, 64, 3), (50, 2, 8)])
+def test_sn_halo_job_covers_band_exactly_diagonal_first(n, w, n_dev):
+    """The RepSN job (one shard-local banded task per device) covers every
+    band pair exactly once when each device's tiles are shifted by its
+    shard origin, including row padding and the halo that wraps round to
+    the last shard; each device lists its diagonal tiles first."""
+    from repro.er.compiler import (A_TILE, B_TILE, RED, TileCatalog, lower,
+                                   sn_halo_job)
+    n_loc = -(-n // n_dev)
+    plan = plan_sorted_neighborhood(n, w, 5)
+    cat = lower(sn_halo_job(plan, n_dev, n_loc), 32, 32)
+    got = []
+    for d in range(n_dev):
+        mine = cat.tiles[cat.tiles[:, RED] == d]
+        off = mine[:, B_TILE] - mine[:, A_TILE]
+        assert (np.diff(off) >= 0).all()
+        ea, eb = enumerate_catalog_pairs(TileCatalog(
+            mine, 32, 32, cat.n_rows_a, cat.n_rows_b, cat.r,
+            cat.total_pairs))
+        got += list(zip((ea + d * n_loc).tolist(), (eb + d * n_loc).tolist()))
+    assert len(got) == len(set(got)) == plan.total_pairs
+    assert set(got) == sn_band_pairs_bruteforce(n, w)

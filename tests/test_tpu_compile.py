@@ -7,7 +7,9 @@ resolved default capacity, at the default 128×128 tile and at the four
 corners of ``GEOMETRY_LATTICE``. Each compile runs with Mosaic's scoped
 VMEM limit set to what ``catalog_vmem_bytes`` predicts, so a pass shows
 both that the kernel lowers and that the VMEM model is never below the
-compiler's own figure — whatever ``check_vmem`` admits compiles.
+compiler's own figure — whatever ``check_vmem`` admits compiles. The
+RepSN halo scorer is compiled for all four chips of the topology at the
+``ds2_sn_mesh4`` cell's size.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
@@ -28,7 +30,7 @@ GEOMETRIES = ((128, 128), (32, 32), (32, 256), (256, 32), (256, 256))
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -43,9 +45,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(one_chip, monkeypatch, bm: int, bn: int, capacity: int):
@@ -86,3 +93,27 @@ def test_catalog_kernel_compiles_for_v5e(one_chip, monkeypatch, geom,
     pair_sim.check_vmem(bm, bn, D, capacity)   # the model admits it ...
     text = _compile(one_chip, monkeypatch, bm, bn, capacity)
     assert "tpu_custom_call" in text           # ... and Mosaic agrees
+
+
+def test_sn_halo_scorer_compiles_for_four_v5e_chips(topo, monkeypatch):
+    """The RepSN halo scorer at ``ds2_sn_mesh4``'s size (173,750 rows
+    over four chips, w = 1,000): the Pallas kernel, fed by neighbour
+    ``collective-permute`` hops and no all-gather."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.er.compiler import make_scorer
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "resolve_impl", lambda impl: impl)
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"))
+    scorer = make_scorer(mesh, "data", mode="halo", threshold=0.55,
+                         impl="pallas", halo=999, compact=True)
+    on_data = NamedSharding(mesh, P("data"))
+    feats = jax.ShapeDtypeStruct((4 * -(-173_750 // 4), D), jnp.float32,
+                                 sharding=on_data)
+    tiles = jax.ShapeDtypeStruct((4, TILES, pair_sim.NCOLS), jnp.int32,
+                                 sharding=on_data)
+    text = scorer._fn.lower(feats, tiles).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text and "all-gather" not in text
