@@ -68,9 +68,12 @@ def featurize(titles: Sequence[str], cfg) -> Tuple[np.ndarray, np.ndarray, np.nd
     """Shared featurization for the batch pipeline and the resident service:
     (codes, lens) for the exact stage-2 verifier plus the hashed n-gram
     filter features. ``cfg`` needs ``max_len`` and ``feature_dim``
-    (ERConfig and ServiceConfig both qualify)."""
-    codes, lens = encode_titles(titles, max_len=cfg.max_len)
-    feats = ngram_features(codes, dim=cfg.feature_dim, lengths=lens)
+    (ERConfig and ServiceConfig both qualify). Spans ``er.featurize.encode``
+    and ``er.featurize.ngrams``, each with the row count ``n``."""
+    with span("featurize.encode", n=len(titles)):
+        codes, lens = encode_titles(titles, max_len=cfg.max_len)
+    with span("featurize.ngrams", n=len(titles)):
+        feats = ngram_features(codes, dim=cfg.feature_dim, lengths=lens)
     return codes, lens, feats
 
 

@@ -65,11 +65,32 @@ def edit_distance(a_codes, a_len, b_codes, b_len):
     return jnp.take_along_axis(dp, b_len[:, None].astype(jnp.int32), axis=1)[:, 0]
 
 
-def edit_similarity(a_codes, a_len, b_codes, b_len):
-    """Normalized similarity 1 − dist / max(len_a, len_b) ∈ [0, 1]."""
-    d = edit_distance(a_codes, a_len, b_codes, b_len).astype(jnp.float32)
-    mx = jnp.maximum(jnp.maximum(a_len, b_len), 1).astype(jnp.float32)
+@functools.lru_cache(maxsize=None)
+def _similarity_table(L: int) -> np.ndarray:
+    """(L + 1, L + 1) float32 whose entry [d, m] is 1 − d / max(m, 1),
+    divided on the host in IEEE float32."""
+    d = np.arange(L + 1, dtype=np.float32)[:, None]
+    mx = np.maximum(np.arange(L + 1), 1).astype(np.float32)
     return 1.0 - d / mx
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def _table_similarity(d, a_len, b_len, L: int):
+    """1 − d / max(a_len, b_len) from ``_similarity_table(L)``, in one
+    dispatch: an eager gather costs several per ``verify_pairs`` chunk."""
+    return jnp.asarray(_similarity_table(L))[d, jnp.maximum(a_len, b_len)]
+
+
+def edit_similarity(a_codes, a_len, b_codes, b_len):
+    """Normalized similarity 1 − dist / max(len_a, len_b) ∈ [0, 1].
+
+    The quotient is read from ``_similarity_table``, so ``>= threshold``
+    gives the same verdict on every backend. A TPU's float32 division is
+    not correctly rounded: there, a pair exactly at the threshold (3
+    edits in 15 bytes at 0.8) came out just under it.
+    """
+    d = edit_distance(a_codes, a_len, b_codes, b_len)
+    return _table_similarity(d, a_len, b_len, a_codes.shape[1])
 
 
 def edit_distance_np(a: str, b: str) -> int:

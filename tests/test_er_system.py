@@ -103,6 +103,56 @@ def test_edit_distance_matches_reference():
     np.testing.assert_array_equal(got, want)
 
 
+# Pairs at similarity exactly 0.8 (d / max length = 1/5) and one edit
+# beyond it.
+THRESHOLD_PAIRS = [("aac max pro 961", "aac max pro 589"),   # 3 edits, 15 bytes
+                   ("aac max pro 961", "aac max pr 5890"),   # 4 edits, 15 bytes
+                   ("abcde", "abcdf"), ("abcde", "abdff"),
+                   ("x" * 20, "x" * 16 + "yyyy"), ("x" * 25, "x" * 20 + "yyyyy"),
+                   ("x" * 25, "x" * 19 + "yyyyyy")]
+
+
+def test_verify_pairs_keeps_pairs_exactly_at_the_threshold():
+    """Similarity exactly 0.8 (d / max length = 1/5) passes stage 2 and
+    one edit more fails, as in the exact rule 10·d <= 2·max length."""
+    from repro.er.compiler import verify_pairs
+
+    pairs = THRESHOLD_PAIRS
+    titles = [t for p in pairs for t in p]
+    codes, lens = encode_titles(titles, 64)
+    rows_a, rows_b = np.arange(0, len(titles), 2), np.arange(1, len(titles), 2)
+    hit_a, _ = verify_pairs(codes, lens, codes, lens, rows_a, rows_b,
+                            threshold=0.8)
+    dist = np.array([edit_distance_np(a, b) for a, b in pairs])
+    mx = np.array([max(len(a), len(b)) for a, b in pairs])
+    np.testing.assert_array_equal(hit_a, rows_a[10 * dist <= 2 * mx])
+    assert (10 * dist == 2 * mx).sum() == 4
+
+
+def test_edit_similarity_is_the_ieee_quotient_inside_jit():
+    """``edit_similarity`` equals 1 − d / max length divided in IEEE
+    float32, bit for bit, and a jitted ``two_stage_match`` keeps the
+    pairs exactly at 0.8 and drops those one edit beyond."""
+    import jax
+    from repro.er.similarity import edit_similarity, two_stage_match
+
+    a, b = (list(t) for t in zip(*THRESHOLD_PAIRS))
+    ca, la = encode_titles(a, 64)
+    cb, lb = encode_titles(b, 64)
+    dist = np.array([edit_distance_np(x, y) for x, y in THRESHOLD_PAIRS])
+    mx = np.maximum(la, lb)
+    want = 1.0 - dist.astype(np.float32) / mx.astype(np.float32)
+    sim = np.asarray(edit_similarity(ca, la, cb, lb))
+    np.testing.assert_array_equal(sim.view(np.uint32), want.view(np.uint32))
+
+    # A margin of 1 lets every pair past the cosine filter.
+    match, scores = jax.jit(two_stage_match)(
+        ngram_features(a), ngram_features(b), ca, la, cb, lb, 0.8, 1.0)
+    np.testing.assert_array_equal(np.asarray(match), 10 * dist <= 2 * mx)
+    np.testing.assert_array_equal(np.asarray(scores).view(np.uint32),
+                                  np.where(match, want, 0).view(np.uint32))
+
+
 def test_default_config_fresh_per_call():
     """run_er must not share a mutable default ERConfig across calls:
     the default is None → a fresh instance, returned on ERResult.config,
