@@ -144,7 +144,7 @@ def run(ds1_n: int = 114_000, ds2_n: int = 1_390_000, quick: bool = False):
                 loads = plan.reducer_pairs
                 # r=10n reducers over n nodes with 2 cores: each core runs
                 # 5 reducers; node time = its reducers' load sum — use the
-                # round-robin node assignment of er.distributed.
+                # round-robin node assignment of compiler.device_assignment.
                 node_of = np.arange(r) % (2 * n)
                 core_loads = np.bincount(node_of, weights=loads,
                                          minlength=2 * n)

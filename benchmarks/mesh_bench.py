@@ -48,7 +48,7 @@ SCRIPT = textwrap.dedent("""
     from dataclasses import replace
     from repro.er import ERConfig, run_er
     from repro.er.datasets import make_products
-    from repro.er.distributed import sn_replication_volume
+    from repro.er.compiler.comms import sn_replication_volume
     from repro.er.compiler.execute import stage1_stats
     from repro.sharding import make_er_mesh
 

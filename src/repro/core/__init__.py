@@ -3,14 +3,12 @@ blocked pairwise workloads — BDM, Basic, BlockSplit, PairRange, and the
 two-source extension, adapted to static-shape SPMD execution on TPU meshes.
 """
 from . import enumeration  # noqa: F401
-from .assignment import greedy_lpt, greedy_lpt_jnp, makespan_stats  # noqa: F401
+from .assignment import greedy_lpt, makespan_stats  # noqa: F401
 from .basic import BasicPlan, plan_basic  # noqa: F401
 from .bdm import (  # noqa: F401
     blocked_layout,
     compute_bdm,
-    compute_bdm_jnp,
     entity_indices,
-    entity_indices_jnp,
     update_bdm,
 )
 from .block_split import BlockSplitPlan, plan_block_split  # noqa: F401

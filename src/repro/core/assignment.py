@@ -1,14 +1,11 @@
-"""Task -> reduce-task assignment (shared by BlockSplit and the MoE layer).
+"""Task -> reduce-task assignment (BlockSplit and the tile scheduler).
 
 The paper's BlockSplit assigns match tasks with a greedy LPT heuristic:
 sort tasks by pair count descending, then repeatedly give the next task to
 the reduce task with the fewest assigned pairs (§IV, Alg. 1 lines 22-27).
 
-Two twins:
-  * :func:`greedy_lpt` — numpy host planning (dynamic task count).
-  * :func:`greedy_lpt_jnp` — jnp/jit-able (static shapes) via lax.scan with
-    a running-load argmin; reused by models/moe.py balanced dispatch where
-    the "tasks" are experts and the loads are token counts.
+:func:`greedy_lpt` is that heuristic on the host; :func:`greedy_lpt_hetero`
+is its heterogeneous-rate variant.
 """
 from __future__ import annotations
 
@@ -16,8 +13,7 @@ from typing import Tuple
 
 import numpy as np
 
-__all__ = ["greedy_lpt", "greedy_lpt_hetero", "greedy_lpt_jnp",
-           "makespan_stats"]
+__all__ = ["greedy_lpt", "greedy_lpt_hetero", "makespan_stats"]
 
 
 def greedy_lpt(weights: np.ndarray, r: int) -> Tuple[np.ndarray, np.ndarray]:
@@ -58,24 +54,6 @@ def greedy_lpt_hetero(weights, rates) -> Tuple[np.ndarray, np.ndarray,
         assignment[t] = k
         loads[k] += w[t]
     return assignment, loads, loads * rates
-
-
-def greedy_lpt_jnp(weights, r: int):
-    """jnp twin of :func:`greedy_lpt` (jit-able; O(T·r) scan)."""
-    import jax
-    import jax.numpy as jnp
-
-    w = weights
-    order = jnp.argsort(-w, stable=True)
-
-    def step(loads, t):
-        k = jnp.argmin(loads)
-        loads = loads.at[k].add(w[t])
-        return loads, k
-
-    loads, bins_sorted = jax.lax.scan(step, jnp.zeros(r, w.dtype), order)
-    assignment = jnp.zeros_like(order).at[order].set(bins_sorted)
-    return assignment, loads
 
 
 def makespan_stats(loads: np.ndarray) -> dict:

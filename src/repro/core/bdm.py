@@ -6,11 +6,7 @@ strategies need — BlockSplit's match-task table and PairRange's ranges are
 deterministic functions of it, which is also the fault-tolerance story: a
 restarted worker recomputes its plan from the checkpointed BDM.
 
-Two implementations:
-  * :func:`compute_bdm` — numpy, host-side (planning path).
-  * :func:`compute_bdm_jnp` — jnp, jit-able (used inside the shard_map
-    distributed job where each device bincounts its local shard; the
-    cross-device reduction is a psum/all_gather in er/distributed.py).
+:func:`compute_bdm` counts it on the host (the planning path).
 
 Entity indexing (paper §V, Fig. 6 "white numbers"): entity e in partition
 Π_i, block Φ_k gets global index = (# entities of Φ_k in Π_0..Π_{i-1}) +
@@ -25,10 +21,8 @@ import numpy as np
 
 __all__ = [
     "compute_bdm",
-    "compute_bdm_jnp",
     "update_bdm",
     "entity_indices",
-    "entity_indices_jnp",
     "blocked_layout",
 ]
 
@@ -69,15 +63,6 @@ def update_bdm(bdm: np.ndarray, block_ids: np.ndarray,
     return out
 
 
-def compute_bdm_jnp(block_ids, partition_ids, num_blocks: int, num_partitions: int):
-    """jnp twin of :func:`compute_bdm` (jit-able; static b, m)."""
-    import jax.numpy as jnp
-
-    flat = block_ids.astype(jnp.int32) * num_partitions + partition_ids.astype(jnp.int32)
-    counts = jnp.bincount(flat, length=num_blocks * num_partitions)
-    return counts.reshape(num_blocks, num_partitions)
-
-
 def _cumcount_by_key(key: np.ndarray) -> np.ndarray:
     """rank[e] = #{e' < e (input order) : key[e'] == key[e]} — vectorized."""
     n = key.shape[0]
@@ -106,33 +91,6 @@ def entity_indices(block_ids: np.ndarray, partition_ids: np.ndarray,
     base = offs[block_ids, partition_ids]
     rank = _cumcount_by_key(block_ids * m + partition_ids)
     return base + rank
-
-
-def entity_indices_jnp(block_ids, partition_ids, bdm):
-    """jnp twin of :func:`entity_indices` (jit-able)."""
-    import jax.numpy as jnp
-
-    b, m = bdm.shape
-    n = block_ids.shape[0]
-    offs = jnp.concatenate(
-        [jnp.zeros((b, 1), bdm.dtype), jnp.cumsum(bdm, axis=1)[:, :-1]], axis=1)
-    base = offs[block_ids, partition_ids]
-    key = block_ids.astype(jnp.int32) * m + partition_ids.astype(jnp.int32)
-    order = jnp.argsort(key, stable=True)
-    sorted_key = key[order]
-    iota = jnp.arange(n, dtype=jnp.int32)
-    new_group = jnp.concatenate(
-        [jnp.ones(1, bool), sorted_key[1:] != sorted_key[:-1]])
-    group_start = jax_cummax(jnp.where(new_group, iota, 0))
-    rank_sorted = iota - group_start
-    rank = jnp.zeros(n, jnp.int32).at[order].set(rank_sorted)
-    return base + rank
-
-
-def jax_cummax(x):
-    from jax import lax
-
-    return lax.cummax(x)
 
 
 def blocked_layout(block_ids: np.ndarray, entity_idx: np.ndarray,

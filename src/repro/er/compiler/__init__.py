@@ -41,8 +41,8 @@ calibrates `schedule_tiles` and drives mid-stream work stealing in the
 supervisor — slow devices' queued tiles are re-placed onto
 faster-projected peers before the round ends.
 
-`er/executor.py` and `er/distributed.py` keep their historical entry
-points as thin shims over this package.
+`er/executor.py` keeps its historical entry points as thin shims over
+this package.
 """
 from .ir import (  # noqa: F401
     A_TILE, B_TILE, R0, R1, C0, C1, TRI, LB_R, LB_C, UB_R, UB_C, BAND, RED,

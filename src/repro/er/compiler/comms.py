@@ -49,6 +49,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ...core.sorted_neighborhood import _w_eff
 from .ir import (A_TILE, B_TILE, R0, R1, C0, C1, LB_R, LB_C, UB_R, UB_C,
                  BAND, TileCatalog)
 
@@ -60,6 +61,7 @@ __all__ = [
     "rewrite_tiles_local",
     "halo_hop_rows",
     "halo_bytes_per_device",
+    "sn_replication_volume",
     "psum_bytes_per_device",
     "default_group",
 ]
@@ -92,6 +94,34 @@ def halo_bytes_per_device(n_loc: int, halo: int, feature_dim: int,
                           itemsize: int = 4) -> List[int]:
     """Per-hop bytes received per device for the RepSN halo exchange."""
     return [r * feature_dim * itemsize for r in halo_hop_rows(n_loc, halo)]
+
+
+def sn_replication_volume(n: int, w: int, n_dev: int, feature_dim: int,
+                          itemsize: int = 4, per_hop: bool = False):
+    """Job-2 interconnect bytes *received* across all devices:
+    (boundary replication, full all-gather) — or, with ``per_hop``, the
+    per-device hop-by-hop byte schedule of the multi-hop halo chain that
+    ``run_er`` runs for SN on a mesh (``n`` divisible by ``n_dev``).
+
+    RepSN replicates only the w−1 boundary rows between adjacent shards —
+    O(n_dev · w · d) — where the generic executors all_gather the whole
+    feature matrix, O(n_dev · n · d). The gap is the SN analog of the
+    paper's map-output-replication accounting (Fig. 12). The accounting
+    matches the executor at ANY window size: when w − 1 > n/n_dev the
+    halo crosses ⌈(w−1)/n_loc⌉ shards via chained hops, but the last hop
+    forwards only the final partial strip, so the total stays exactly
+    n_dev · (w−1) · d · itemsize — ``per_hop=True`` returns the
+    per-device hop list [n_loc·row_bytes, …, take·row_bytes] summing to
+    (w−1) · d · itemsize (the 2-tuple form sums it across devices).
+    """
+    halo = _w_eff(n, w) - 1
+    if n_dev <= 1:          # single device: the halo ppermute is a
+        return ([] if per_hop else (0, 0))   # self-send — nothing
+    n_loc = n // n_dev      # crosses the wire
+    if per_hop:
+        return halo_bytes_per_device(n_loc, halo, feature_dim, itemsize)
+    return (n_dev * halo * feature_dim * itemsize,
+            n_dev * (n - n_loc) * feature_dim * itemsize)
 
 
 def psum_bytes_per_device(n_model: int, num_tiles: int, block_m: int,

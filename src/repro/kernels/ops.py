@@ -14,8 +14,6 @@ from __future__ import annotations
 import jax
 
 from . import ref
-from .flash_attention import flash_attention as _flash
-from .grouped_mm import grouped_matmul as _gmm, pad_groups  # noqa: F401
 from .pair_sim import COMPACT_CAPACITY
 from .pair_sim import pair_scores as _pair_scores
 from .pair_sim import pair_scores_catalog as _pair_scores_catalog
@@ -24,7 +22,7 @@ from .pair_sim import \
 
 __all__ = ["pair_scores", "pair_scores_catalog",
            "pair_scores_catalog_raw", "pair_scores_catalog_compact",
-           "grouped_matmul", "attention", "pad_groups", "resolve_impl"]
+           "resolve_impl"]
 
 
 def resolve_impl(impl: str) -> str:
@@ -100,21 +98,3 @@ def pair_scores_catalog_compact(a, b, catalog, *, threshold: float = 0.8,
         block_n=block_n, capacity=capacity,
         interpret=(impl == "interpret"))
 
-
-def grouped_matmul(x, tile_expert, w, *, block_t: int = 128,
-                   block_f: int = 128, impl: str = "pallas"):
-    impl = resolve_impl(impl)
-    if impl == "xla":
-        return ref.grouped_matmul_ref(x, tile_expert, w, block_t=block_t)
-    return _gmm(x, tile_expert, w, block_t=block_t, block_f=block_f,
-                interpret=(impl == "interpret"))
-
-
-def attention(q, k, v, *, causal: bool = True, scale: float | None = None,
-              block_q: int = 512, block_k: int = 512, impl: str = "xla"):
-    impl = resolve_impl(impl)
-    if impl == "xla":
-        return ref.attention_ref(q, k, v, causal=causal, scale=scale)
-    return _flash(q, k, v, causal=causal, scale=scale,
-                  block_q=block_q, block_k=block_k,
-                  interpret=(impl == "interpret"))

@@ -48,11 +48,10 @@ Two entry points:
     fused plan executor (er/executor.py, DESIGN.md §Catalog): the grid is
     one-dimensional over catalog entries; a scalar-prefetch operand (the
     catalog, SMEM) feeds the strip DMAs so each grid step pulls the two
-    feature strips named by the current entry — the same pattern
-    grouped_mm.py uses for expert tiles. The kernel applies the entry's
-    validity window, triangular mask and PairRange corner cuts in-kernel
-    and writes a per-tile survivor mask; the host compacts survivors and
-    runs the exact verifier only on them.
+    feature strips named by the current entry. The kernel applies the
+    entry's validity window, triangular mask and PairRange corner cuts
+    in-kernel and writes a per-tile survivor mask; the host compacts
+    survivors and runs the exact verifier only on them.
 """
 from __future__ import annotations
 
@@ -300,6 +299,24 @@ def _catalog_kernel(cat_ref, a_hbm, b_hbm, o_ref, a_buf, b_buf, a_sem,
     o_ref[...] = keep[None].astype(jnp.float32)
 
 
+def pltpu_prefetch(grid_spec: pl.GridSpec, num_scalar_prefetch: int,
+                   scratch_shapes=None):
+    """Build a PrefetchScalarGridSpec from a plain GridSpec.
+
+    ``scratch_shapes`` (e.g. ``pltpu.VMEM`` buffers and DMA semaphores
+    for manual double-buffered strip copies) pass through verbatim.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch,
+        grid=grid_spec.grid,
+        in_specs=grid_spec.in_specs,
+        out_specs=grid_spec.out_specs,
+        scratch_shapes=tuple(scratch_shapes or ()),
+    )
+
+
 def _catalog_specs(block_m: int, block_n: int, d: int, a_dtype, b_dtype):
     """HBM-resident input specs + double-buffered scratch for the catalog
     kernels: the features stay in ANY (= HBM) and the kernel pulls strips
@@ -333,8 +350,6 @@ def pair_scores_catalog(a, b, catalog, *, threshold: float = 0.8,
     the whole plan executes as ONE pallas_call regardless of how many
     match tasks / blocks it covers, with copy-in off the critical path.
     """
-    from .grouped_mm import pltpu_prefetch
-
     m, d = a.shape
     n = b.shape[0]
     t = catalog.shape[0]
@@ -446,8 +461,6 @@ def pair_scores_catalog_compact(a, b, catalog, *, threshold: float = 0.8,
     Strips arrive by the same double-buffered DMA as the mask variant.
     """
     from jax.experimental.pallas import tpu as pltpu
-
-    from .grouped_mm import pltpu_prefetch
 
     m, d = a.shape
     n = b.shape[0]

@@ -13,8 +13,7 @@ from .pair_sim import COMPACT_CAPACITY, SCORE_PRECISION
 
 __all__ = ["pair_scores_ref", "pair_scores_catalog_ref",
            "pair_scores_catalog_raw_ref", "pair_scores_catalog_compact_ref",
-           "pack_survivor_mask",
-           "grouped_matmul_ref", "attention_ref"]
+           "pack_survivor_mask"]
 
 
 def pair_scores_ref(a, b, *, threshold: float = 0.8, triangular: bool = False):
@@ -122,28 +121,3 @@ def pair_scores_catalog_compact_ref(a, b, catalog, *, threshold: float = 0.8,
                                     block_m=block_m, block_n=block_n)
     return pack_survivor_mask(masks, capacity)
 
-
-def grouped_matmul_ref(x, tile_expert, w, *, block_t: int = 128):
-    """x: (T, d) tile-aligned expert-sorted tokens; w: (E, d, F)."""
-    t, _ = x.shape
-    expert_of_token = jnp.repeat(tile_expert, block_t)
-    w_tok = w[expert_of_token]                       # (T, d, F)
-    return jnp.einsum("td,tdf->tf", x, w_tok,
-                      preferred_element_type=jnp.float32).astype(x.dtype)
-
-
-def attention_ref(q, k, v, *, causal: bool = True, scale: float | None = None):
-    """q: (B, H, S, D); k, v: (B, KVH, S, D). Plain softmax attention."""
-    b, h, s, d = q.shape
-    kvh = k.shape[1]
-    if scale is None:
-        scale = d ** -0.5
-    k = jnp.repeat(k, h // kvh, axis=1)
-    v = jnp.repeat(v, h // kvh, axis=1)
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
-    if causal:
-        mask = jnp.tril(jnp.ones((s, s), bool))
-        logits = jnp.where(mask, logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(p.dtype)).astype(q.dtype)

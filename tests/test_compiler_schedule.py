@@ -22,13 +22,13 @@ fuzzes their inputs):
 import numpy as np
 import pytest
 
-from repro.core import (plan_basic, plan_block_split, plan_pair_range,
-                        plan_sorted_neighborhood)
+from repro.core import (compute_bdm, plan_basic, plan_block_split,
+                        plan_pair_range, plan_sorted_neighborhood)
 from repro.core.two_source import TwoSourceBDM, plan_pair_range_2src
 from repro.er import (ERConfig, ERService, ServiceConfig, cross_restrict,
                       make_products, run_er)
 from repro.er.blocking import exponential_block_ids
-from repro.er.compiler import (apply_schedule, cross_job,
+from repro.er.compiler import (apply_schedule, cross_job, device_assignment,
                                enumerate_catalog_pairs, lower, plan_to_job,
                                schedule_tiles, tile_costs, tiles_for_devices)
 from repro.er.compiler.ir import R1, RED, TileCatalog
@@ -180,6 +180,36 @@ def test_schedule_respects_healthy_mask():
         dead = np.flatnonzero(~healthy)
         assert not np.isin(sched.reducer_device, dead).any()
         assert sched.device_load[dead].sum() == 0
+
+
+def test_device_assignment_respreads_over_healthy_devices():
+    """Without a schedule, reducers go round-robin over the healthy
+    devices only, evenly to within one."""
+    healthy = np.ones(8, bool)
+    healthy[[2, 5]] = False
+    assign = device_assignment(32, 8, healthy)
+    assert set(assign.tolist()) == set(np.flatnonzero(healthy).tolist())
+    counts = np.bincount(assign, minlength=8)
+    assert counts[~healthy].sum() == 0
+    assert counts[healthy].max() - counts[healthy].min() <= 1
+
+
+def test_tiles_for_devices_routes_reducers_round_robin():
+    """Without a schedule, every live tile of a two-source catalog lands
+    on its reducer's round-robin device; padding entries are empty."""
+    qb = np.asarray([0, 0, 1, 2] * 4)
+    bdm2 = TwoSourceBDM(
+        bdm_r=compute_bdm(np.arange(16) % 3, np.zeros(16, np.int64), 3, 1),
+        bdm_s=compute_bdm(qb, np.zeros_like(qb), 3, 1))
+    cat = lower(plan_to_job(plan_pair_range_2src(bdm2, 16)), 16, 16)
+    tiles_dev = tiles_for_devices(cat, 8)
+    dev_of = device_assignment(16, 8)
+    live = 0
+    for d in range(8):
+        mine = tiles_dev[d][tiles_dev[d][:, R1] > 0]
+        assert (dev_of[mine[:, RED]] == d).all()
+        live += mine.shape[0]
+    assert live == cat.num_tiles
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,7 @@ SCRIPT = textwrap.dedent("""
     import jax, numpy as np
     from repro.er import ERConfig, make_products, run_er
     from repro.er.compiler import stage1_stats
-    from repro.er.distributed import sn_replication_volume
+    from repro.er.compiler.comms import sn_replication_volume
 
     mesh = jax.make_mesh((8,), ("data",),
                          axis_types=(jax.sharding.AxisType.Auto,))

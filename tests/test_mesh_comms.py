@@ -241,7 +241,7 @@ MULTI_HOP = textwrap.dedent("""
     import jax, numpy as np
     from repro.er import ERConfig, make_products, run_er
     from repro.er.compiler import stage1_stats
-    from repro.er.distributed import sn_replication_volume
+    from repro.er.compiler.comms import sn_replication_volume
     from repro.sharding import make_er_mesh
     from sn_oracle import sn_oracle_matches
 

@@ -1,8 +1,10 @@
 """Two-source plan oracles (paper Appendix I): brute-force R × S pair
 enumeration per block vs ``plan_block_split_2src`` /
 ``plan_pair_range_2src`` — coverage, disjointness, row-mapping, and the
-paper's imbalance bounds — on hypothesis-generated skewed BDMs, plus the
-cross-tile catalog compilers that wire these plans into the executor.
+imbalance bounds (Alg. 2's ceil split, list scheduling, and Graham's
+LPT factor against the exact optimum) — on hypothesis-generated skewed
+BDMs, plus the cross-tile catalog compilers that wire these plans into
+the executor.
 (Closes the gap where only ``test_two_source_plans_cover`` existed.)
 """
 import numpy as np
@@ -10,6 +12,7 @@ import pytest
 
 pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
+from lpt_oracle import exact_makespan
 
 from repro.core.two_source import (TwoSourceBDM, pairs_of_range_2src,
                                    plan_block_split_2src,
@@ -83,7 +86,7 @@ def test_pair_range_2src_partitions_and_balance(bdm2, r):
 
 
 @given(skewed_bdm2(), st.integers(1, 16))
-@settings(max_examples=40, deadline=None)
+@settings(max_examples=40, deadline=None, derandomize=True)
 def test_block_split_2src_covers_and_lpt_bound(bdm2, r):
     plan = plan_block_split_2src(bdm2, r)
     cells, rows, er, es = _brute_pairs(bdm2)
@@ -100,13 +103,43 @@ def test_block_split_2src_covers_and_lpt_bound(bdm2, r):
                 assert p not in got_rows        # disjoint tasks
                 got_rows.add(p)
     assert got_rows == rows                     # exhaustive
-    # Paper's bound: greedy LPT keeps makespan within (4/3 − 1/3r)·OPT,
-    # OPT >= max(P/r, largest match task).
+    # Greedy LPT is a list schedule: r·max_load <= P + (r − 1)·w_max.
     if plan.total_pairs:
         w_max = int(plan.task_pairs.max())
-        opt_lb = max(plan.total_pairs / r, w_max)
-        assert int(plan.reducer_pairs.max()) <= \
-            (4 / 3 - 1 / (3 * r)) * opt_lb + 1e-9
+        assert r * int(plan.reducer_pairs.max()) <= \
+            plan.total_pairs + (r - 1) * w_max
+
+
+# (bdm_r, bdm_s, r): small two-source BDMs whose plans have at most 10
+# match tasks — whole blocks (the first three give LPT Graham's tight
+# family and the [9, 9, 6, 10, 10] instance) and blocks split into
+# partition-pair tasks.
+GRAHAM_BDMS = [
+    ([[3], [3], [2], [2], [2]], [[1]] * 5, 2),
+    ([[5], [5], [4], [4], [3], [3], [3]], [[1]] * 7, 3),
+    ([[3], [3], [2], [5], [5]], [[3], [3], [3], [2], [2]], 3),
+    ([[6, 3], [2, 0], [1, 0]], [[2, 1], [2, 0], [3, 0]], 2),
+    ([[10, 5, 0], [0, 3, 1], [2, 2, 2]], [[4, 2], [1, 1], [0, 0]], 4),
+    ([[17], [17], [14], [10], [10], [7], [7], [6], [6]], [[1]] * 9, 5),
+    ([[7], [13], [4], [5], [2], [4], [7], [3], [2], [1]],
+     [[2], [1], [3], [2], [5], [2], [1], [2], [3], [1]], 4),
+    ([[4, 3, 2], [1, 1, 0]], [[3, 1], [2, 0]], 2),
+]
+
+
+@pytest.mark.parametrize("bdm_r,bdm_s,r", GRAHAM_BDMS)
+def test_block_split_2src_within_graham_of_exact_opt(bdm_r, bdm_s, r):
+    """BlockSplit's greedy LPT over its match tasks against the exact
+    optimum: 3r·LPT <= (4r − 1)·OPT."""
+    plan = plan_block_split_2src(
+        TwoSourceBDM(bdm_r=np.asarray(bdm_r, np.int64),
+                     bdm_s=np.asarray(bdm_s, np.int64)), r)
+    assert 0 < plan.task_pairs.size <= 10
+    assert int(plan.task_pairs.sum()) == plan.total_pairs
+    lpt = int(plan.reducer_pairs.max())
+    opt = exact_makespan(plan.task_pairs.tolist(), r)
+    assert opt <= lpt
+    assert 3 * r * lpt <= (4 * r - 1) * opt
 
 
 @given(skewed_bdm2(), st.integers(1, 12))
