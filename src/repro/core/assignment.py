@@ -9,6 +9,7 @@ is its heterogeneous-rate variant.
 """
 from __future__ import annotations
 
+import heapq
 from typing import Tuple
 
 import numpy as np
@@ -24,12 +25,18 @@ def greedy_lpt(weights: np.ndarray, r: int) -> Tuple[np.ndarray, np.ndarray]:
     """
     w = np.asarray(weights, np.int64)
     order = np.argsort(-w, kind="stable")
+    # The least (load, bin) pair is the argmin with the lowest bin.
+    heap = [(0, k) for k in range(r)]
+    bins = []
+    for wt in w[order].tolist():
+        load, k = heap[0]
+        bins.append(k)
+        heapq.heapreplace(heap, (load + wt, k))
     assignment = np.empty(w.shape[0], np.int64)
+    assignment[order] = bins
     loads = np.zeros(r, np.int64)
-    for t in order:
-        k = int(np.argmin(loads))
-        assignment[t] = k
-        loads[k] += w[t]
+    for load, k in heap:
+        loads[k] = load
     return assignment, loads
 
 

@@ -61,7 +61,6 @@ from .lower import (  # noqa: F401
     lower,
     pad_catalog,
     pad_tiles,
-    task_tiles,
 )
 from .comms import (  # noqa: F401
     COMMS_POLICIES,
@@ -79,6 +78,7 @@ from .schedule import (  # noqa: F401
     Schedule,
     apply_schedule,
     device_assignment,
+    row_sum_tiles,
     schedule_tiles,
     tile_costs,
     tiles_for_devices,

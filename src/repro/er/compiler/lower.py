@@ -25,12 +25,11 @@ from typing import Tuple
 import numpy as np
 
 from .ir import (A_TILE, B_TILE, R0, R1, C0, C1, TRI, LB_R, LB_C, UB_R,
-                 UB_C, BAND, RED, NCOLS, NO_LB, NO_UB, RED_FREE,
+                 UB_C, BAND, RED, NCOLS, RED_FREE, TASK_NCOLS,
                  T_A0, T_ALEN, T_B0, T_BLEN, T_TRI, T_LB_R, T_LB_C,
                  T_UB_R, T_UB_C, T_BAND, T_RED, MatchJob, TileCatalog)
 
 __all__ = [
-    "task_tiles",
     "lower",
     "pad_tiles",
     "pad_catalog",
@@ -39,56 +38,27 @@ __all__ = [
 ]
 
 
-def task_tiles(a0: int, alen: int, b0: int, blen: int, tri: bool,
-               reducer: int, bm: int, bn: int,
-               lb: Tuple[int, int] = (NO_LB, NO_LB),
-               ub: Tuple[int, int] = (NO_UB, NO_UB),
-               band: int = 0) -> np.ndarray:
-    """Aligned tiles intersecting one task's [a0, a0+alen) × [b0, b0+blen)
-    window. Validity windows/cuts are global-row predicates, so every tile
-    of a task carries the same scalars; triangular tasks drop tiles
-    entirely on/below the diagonal (no row < col cell), banded tasks
-    additionally drop tiles entirely above the col − row < band diagonal —
-    the tile set hugs the band instead of filling the bounding rectangle
-    — and list their tiles diagonal by diagonal, nearest first."""
-    if alen <= 0 or blen <= 0:
-        return np.zeros((0, NCOLS), np.int32)
-    ii = np.arange(a0 // bm, -(-(a0 + alen) // bm), dtype=np.int64)
-    jj = np.arange(b0 // bn, -(-(b0 + blen) // bn), dtype=np.int64)
-    tii, tjj = np.meshgrid(ii, jj, indexing="ij")
-    tii, tjj = tii.ravel(), tjj.ravel()
-    if tri:
-        keep = np.maximum(tii * bm, a0) < np.minimum((tjj + 1) * bn, b0 + blen)
-        tii, tjj = tii[keep], tjj[keep]
-    if band > 0:
-        # Some cell with col − row < band: min over the tile∩window of
-        # (col − row) is clipped_col_start − (clipped_row_end − 1).
-        keep = (np.maximum(tjj * bn, b0)
-                < np.minimum((tii + 1) * bm, a0 + alen) + band - 1)
-        # Diagonal tiles first: they hold the nearest sort neighbours,
-        # the band's densest survivors, so the stage-1 launches whose
-        # tiles overflow the compact capacity (and re-score through the
-        # mask path) are the first ones, not every one.
-        order = np.lexsort((tii[keep], tjj[keep] - tii[keep]))
-        tii, tjj = tii[keep][order], tjj[keep][order]
-    t = np.empty((tii.size, NCOLS), np.int32)
-    t[:, A_TILE] = tii
-    t[:, B_TILE] = tjj
-    t[:, R0] = a0
-    t[:, R1] = a0 + alen
-    t[:, C0] = b0
-    t[:, C1] = b0 + blen
-    t[:, TRI] = int(tri)
-    t[:, LB_R], t[:, LB_C] = lb
-    t[:, UB_R], t[:, UB_C] = ub
-    t[:, BAND] = band
-    t[:, RED] = reducer
-    return t
+def _rank(counts: np.ndarray) -> np.ndarray:
+    """0, 1, …, counts[g] − 1 for each group g in turn: each element's
+    place in its group of ``np.repeat(…, counts)``."""
+    return np.arange(int(counts.sum())) - np.repeat(
+        np.cumsum(counts) - counts, counts)
 
 
 def lower(job: MatchJob, block_m: int = 128,
           block_n: int = 128) -> TileCatalog:
-    """Tile a MatchJob into an MXU tile catalog.
+    """Tile a MatchJob into an MXU tile catalog, in array passes over
+    the task table.
+
+    Each task's [a0, a0+alen) × [b0, b0+blen) window is intersected with
+    the aligned grid, tiles listed task by task and, within a task, row
+    by row. Validity windows/cuts are global-row predicates, so every
+    tile of a task carries the same scalars; triangular tasks drop tiles
+    entirely on/below the diagonal (no row < col cell), banded tasks
+    additionally drop tiles entirely above the col − row < band diagonal
+    — the tile set hugs the band instead of filling the bounding
+    rectangle — and list their tiles diagonal by diagonal, nearest
+    first.
 
     Tiles inherit their task's reducer attribution; tasks marked
     :data:`ir.RED_FREE` (no planner attribution, e.g. the match_⊥ cross
@@ -96,16 +66,52 @@ def lower(job: MatchJob, block_m: int = 128,
     the cost-LPT scheduler re-places everything anyway, this only keeps
     the unscheduled catalog balanced for the legacy/round-robin paths.
     """
-    parts = []
-    for t in job.tasks:
-        parts.append(task_tiles(
-            int(t[T_A0]), int(t[T_ALEN]), int(t[T_B0]), int(t[T_BLEN]),
-            bool(t[T_TRI]), int(t[T_RED]), block_m, block_n,
-            lb=(int(t[T_LB_R]), int(t[T_LB_C])),
-            ub=(int(t[T_UB_R]), int(t[T_UB_C])),
-            band=int(t[T_BAND])))
-    tiles = (np.concatenate(parts, axis=0) if parts
-             else np.zeros((0, NCOLS), np.int32))
+    bm, bn = block_m, block_n
+    t = job.tasks.astype(np.int64).reshape(-1, TASK_NCOLS)
+    a0, alen, b0, blen = t[:, T_A0], t[:, T_ALEN], t[:, T_B0], t[:, T_BLEN]
+    a1, b1 = a0 + alen, b0 + blen
+    i0 = a0 // bm
+    n_rows = np.where((alen > 0) & (blen > 0), -(-a1 // bm) - i0, 0)
+    # One entry per (task, tile row i): the row's clipped cell rows
+    # [ra, rb) and its kept tile columns [jl, jh]. Both predicates are
+    # monotone in the column, so each row keeps one run of tiles.
+    rt = np.repeat(np.arange(t.shape[0]), n_rows)
+    i = i0[rt] + _rank(n_rows)
+    ra = np.maximum(i * bm, a0[rt])
+    rb = np.minimum((i + 1) * bm, a1[rt])
+    jl = b0[rt] // bn
+    jh = -(-b1[rt] // bn) - 1
+    # Triangular: some cell with row < col — min((j+1)·bn, b1) > ra.
+    tri = t[rt, T_TRI] != 0
+    jl = np.where(tri, np.maximum(jl, ra // bn), jl)
+    jh = np.where(tri & (b1[rt] <= ra), jl - 1, jh)
+    # Banded: some cell with col − row < band — the min over the
+    # tile∩window of (col − row) is clipped_col_start − (rb − 1), so
+    # max(j·bn, b0) < rb + band − 1.
+    band = t[rt, T_BAND]
+    reach = rb + band - 1
+    jh = np.where(band > 0, np.minimum(jh, (reach - 1) // bn), jh)
+    jh = np.where((band > 0) & (b0[rt] >= reach), jl - 1, jh)
+    n_cols = np.maximum(jh - jl + 1, 0)
+    task = np.repeat(rt, n_cols)
+    tii = np.repeat(i, n_cols)
+    tjj = np.repeat(jl, n_cols) + _rank(n_cols)
+    banded = t[task, T_BAND] > 0
+    if banded.any():
+        # Diagonal tiles first: they hold the nearest sort neighbours,
+        # the band's densest survivors, so the stage-1 launches whose
+        # tiles overflow the compact capacity (and re-score through the
+        # mask path) are the first ones, not every one.
+        order = np.lexsort((tii, np.where(banded, tjj - tii, 0), task))
+        task, tii, tjj = task[order], tii[order], tjj[order]
+    scalars = np.stack([
+        a0, a1, b0, b1, t[:, T_TRI] != 0,
+        t[:, T_LB_R], t[:, T_LB_C], t[:, T_UB_R], t[:, T_UB_C],
+        t[:, T_BAND], t[:, T_RED]], axis=1).astype(np.int32)
+    tiles = np.empty((task.size, NCOLS), np.int32)
+    tiles[:, A_TILE] = tii
+    tiles[:, B_TILE] = tjj
+    tiles[:, R0:] = scalars[task]
     free = tiles[:, RED] == RED_FREE
     if free.any():
         tiles[free, RED] = (np.arange(int(free.sum()), dtype=np.int32)

@@ -8,7 +8,9 @@ boundary may hold 3 live pairs while an interior tile holds bm·bn. The
 cost model here is **exact**: every predicate the kernel evaluates
 (validity window, triangular mask, lb/ub corner cuts, the SN band) is a
 per-row column *interval* constraint, so the live count is a sum of bm
-interval lengths — O(T·bm), closed form, no enumeration.
+interval lengths. Rectangles and diagonal-cut tiles sum that in closed
+form; only tiles with a band or a corner cut inside them take the O(bm)
+row sum. No enumeration.
 
 ``schedule_tiles`` feeds those costs to ``core.assignment.greedy_lpt``
 twice — tiles → r reducers, then reducer loads → healthy devices —
@@ -32,6 +34,7 @@ __all__ = [
     "NoHealthyDevicesError",
     "Schedule",
     "tile_costs",
+    "row_sum_tiles",
     "schedule_tiles",
     "apply_schedule",
     "tiles_for_devices",
@@ -52,35 +55,84 @@ _COST_SLAB = 65_536     # tiles per cost-model slab: caps peak memory at
 POLICIES = ("cost_lpt", "round_robin")
 
 
-def tile_costs(catalog: TileCatalog) -> np.ndarray:
-    """Exact live-pair count per tile under ALL catalog predicates.
+def _clipped(t: np.ndarray, bm: int, bn: int):
+    """Each tile's live row interval [r0, r1) and column interval
+    [c0, c1): its validity window cut to the tile's own cells."""
+    r0 = np.maximum(t[:, R0], t[:, A_TILE] * bm)
+    r1 = np.minimum(t[:, R1], (t[:, A_TILE] + 1) * bm)
+    c0 = np.maximum(t[:, C0], t[:, B_TILE] * bn)
+    c1 = np.minimum(t[:, C1], (t[:, B_TILE] + 1) * bn)
+    return r0, r1, c0, c1
+
+
+def row_sum_tiles(catalog: TileCatalog) -> np.ndarray:
+    """(T,) bool: the tiles whose live count :func:`tile_costs` sums row
+    by row — a band, or an lb/ub corner cut that reaches inside the
+    tile's clipped window. The lb cut drops cells only on rows ≤ lb_r
+    and only if lb_c > c0; the ub cut only on rows ≥ ub_r and only if
+    ub_c < c1 − 1. Every other tile is a rectangle, or a rectangle cut
+    by the diagonal, and has a closed form."""
+    t = catalog.tiles.astype(np.int64)
+    return _cuts_inside(t, *_clipped(t, catalog.block_m, catalog.block_n))
+
+
+def _cuts_inside(t, r0, r1, c0, c1) -> np.ndarray:
+    cut = (((t[:, LB_R] >= r0) & (t[:, LB_C] > c0))
+           | ((t[:, UB_R] < r1) & (t[:, UB_C] < c1 - 1)))
+    return (t[:, BAND] > 0) | (cut & (r1 > r0) & (c1 > c0))
+
+
+def _row_sums(t: np.ndarray, bm: int, bn: int) -> np.ndarray:
+    """Live count of int64 tiles ``t`` as a sum over their bm rows.
 
     For a fixed row, every predicate constrains the column to one
     interval: the validity window gives [c0, c1), the tile bounds give
     [b_tile·bn, (b_tile+1)·bn), tri demands col ≥ row+1, the band
     demands col < row+band, the lb cut applies col ≥ lb_c only on rows
     ≤ lb_r, the ub cut applies col ≤ ub_c only on rows ≥ ub_r. The live
-    count is Σ_rows max(0, hi − lo) — exact, vectorized, O(T·bm)."""
-    tiles = catalog.tiles
-    if tiles.shape[0] == 0:
-        return np.zeros(0, np.int64)
-    bm, bn = catalog.block_m, catalog.block_n
+    count is Σ_rows max(0, hi − lo)."""
     ar = np.arange(bm, dtype=np.int64)[None, :]
-    out = np.empty(tiles.shape[0], np.int64)
-    for s in range(0, tiles.shape[0], _COST_SLAB):
-        t = tiles[s:s + _COST_SLAB].astype(np.int64)
-        rows = t[:, A_TILE, None] * bm + ar
-        lo = np.maximum(t[:, C0, None], t[:, B_TILE, None] * bn)
-        hi = np.minimum(t[:, C1, None], (t[:, B_TILE, None] + 1) * bn)
-        lo = np.where(t[:, TRI, None] != 0, np.maximum(lo, rows + 1), lo)
-        hi = np.where(t[:, BAND, None] > 0,
-                      np.minimum(hi, rows + t[:, BAND, None]), hi)
-        lo = np.where(rows <= t[:, LB_R, None],
-                      np.maximum(lo, t[:, LB_C, None]), lo)
-        hi = np.where(rows >= t[:, UB_R, None],
-                      np.minimum(hi, t[:, UB_C, None] + 1), hi)
-        valid = (rows >= t[:, R0, None]) & (rows < t[:, R1, None])
+    out = np.empty(t.shape[0], np.int64)
+    for s in range(0, t.shape[0], _COST_SLAB):
+        ts = t[s:s + _COST_SLAB]
+        rows = ts[:, A_TILE, None] * bm + ar
+        lo = np.maximum(ts[:, C0, None], ts[:, B_TILE, None] * bn)
+        hi = np.minimum(ts[:, C1, None], (ts[:, B_TILE, None] + 1) * bn)
+        lo = np.where(ts[:, TRI, None] != 0, np.maximum(lo, rows + 1), lo)
+        hi = np.where(ts[:, BAND, None] > 0,
+                      np.minimum(hi, rows + ts[:, BAND, None]), hi)
+        lo = np.where(rows <= ts[:, LB_R, None],
+                      np.maximum(lo, ts[:, LB_C, None]), lo)
+        hi = np.where(rows >= ts[:, UB_R, None],
+                      np.minimum(hi, ts[:, UB_C, None] + 1), hi)
+        valid = (rows >= ts[:, R0, None]) & (rows < ts[:, R1, None])
         out[s:s + _COST_SLAB] = (np.maximum(hi - lo, 0) * valid).sum(axis=1)
+    return out
+
+
+def tile_costs(catalog: TileCatalog) -> np.ndarray:
+    """Exact live-pair count per tile under ALL catalog predicates.
+
+    On the clipped window [r0, r1) × [c0, c1) a rectangle holds nr·nc
+    cells. A triangular tile keeps the full width on its rows below c0
+    and, on the rows from max(r0, c0), the arithmetic series
+    c1 − 1 − row down to 1. Only the :func:`row_sum_tiles` — a band or
+    a live corner cut — take the O(bm) row sum."""
+    t = catalog.tiles.astype(np.int64)
+    r0, r1, c0, c1 = _clipped(t, catalog.block_m, catalog.block_n)
+    nr = np.maximum(r1 - r0, 0)
+    nc = np.maximum(c1 - c0, 0)
+    tri = t[:, TRI] != 0
+    s = np.maximum(r0, c0)
+    e = np.minimum(r1, c1 - 1)
+    k = np.maximum(e - s, 0)
+    out = np.where(tri,
+                   np.clip(c0 - r0, 0, nr) * nc
+                   + k * ((c1 - 1 - s) + (c1 - e)) // 2,
+                   nr * nc)
+    cut = _cuts_inside(t, r0, r1, c0, c1)
+    if cut.any():
+        out[cut] = _row_sums(t[cut], catalog.block_m, catalog.block_n)
     return out
 
 

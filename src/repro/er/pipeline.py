@@ -56,7 +56,8 @@ from .encode import encode_titles, ngram_features
 from .compiler import (apply_schedule, autotune, cross_job,
                        enumerate_task_pairs, execute, execute_supervised,
                        halo_hop_rows, lower, match_catalog, plan_to_job,
-                       schedule_tiles, sn_halo_job, verify_pairs)
+                       row_sum_tiles, schedule_tiles, sn_halo_job,
+                       verify_pairs)
 from .trace import span
 
 __all__ = ["ERConfig", "ERResult", "run_er", "featurize", "cross_restrict"]
@@ -444,6 +445,8 @@ def _run_er(titles, n, cfg, block_ids, supervised, fault_injector,
         exec_feats, model_axis, comms_plan = g_feats, None, None
         halo, base = 0, None
         with span("schedule", devices=n_dev) as sp:
+            # Tiles whose cost takes the row sum, not the closed form.
+            sp.set_metadata(rowsum_tiles=int(row_sum_tiles(catalog).sum()))
             if mesh is not None:
                 n_model = (int(mesh.shape["model"])
                            if "model" in mesh.axis_names and axis != "model"

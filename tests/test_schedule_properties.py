@@ -16,7 +16,13 @@ from hypothesis import given, settings, strategies as st
 from repro.core import (plan_basic, plan_block_split, plan_pair_range,
                         plan_sorted_neighborhood)
 from repro.core.two_source import TwoSourceBDM, plan_pair_range_2src
-from repro.er.compiler import cross_job, lower, plan_to_job
+from repro.er.compiler import (cross_job, lower, plan_to_job,
+                               row_sum_tiles, tile_costs)
+from repro.er.compiler.ir import (A_TILE, B_TILE, BAND, C0, C1, LB_C, LB_R,
+                                  NCOLS, NO_LB, NO_UB, R0, R1, UB_C, UB_R,
+                                  TileCatalog)
+
+import lower_oracle
 
 from test_compiler_schedule import (check_lpt_beats_round_robin,
                                     check_lpt_within_tile_quantum,
@@ -76,6 +82,56 @@ def dominant_block_bdm(draw):
 @settings(max_examples=40, deadline=None)
 def test_tile_costs_exact(cat):
     check_tile_costs_exact(cat)
+
+
+@st.composite
+def raw_tiles(draw):
+    """Catalog rows straight from the predicate vocabulary: windows that
+    miss, clip or cover their tile, with or without tri, each lb/ub cut
+    absent, dead or live, and a band or none."""
+    bm, bn = draw(st.sampled_from([(8, 8), (8, 16), (16, 8)]))
+    rows = []
+    for _ in range(draw(st.integers(1, 24))):
+        i, j = draw(st.integers(0, 4)), draw(st.integers(0, 4))
+        r_at = st.integers(i * bm - bm // 2, (i + 1) * bm + bm // 2)
+        c_at = st.integers(j * bn - bn // 2, (j + 1) * bn + bn // 2)
+        lb = draw(st.one_of(st.just((NO_LB, NO_LB)), st.tuples(r_at, c_at)))
+        ub = draw(st.one_of(st.just((NO_UB, NO_UB)), st.tuples(r_at, c_at)))
+        rows.append([i, j, draw(r_at), draw(r_at), draw(c_at), draw(c_at),
+                     draw(st.integers(0, 1)), *lb, *ub,
+                     draw(st.one_of(st.just(0), st.integers(1, 3 * bn))),
+                     0])
+    tiles = np.asarray(rows, np.int32).reshape(-1, NCOLS)
+    return TileCatalog(tiles=tiles, block_m=bm, block_n=bn, n_rows_a=0,
+                       n_rows_b=0, r=1, total_pairs=0)
+
+
+def _cuts_a_cell(cat):
+    """Per tile, from its cells: a band, or an lb/ub cut that drops some
+    cell of the tile's validity window."""
+    bm, bn = cat.block_m, cat.block_n
+    out = []
+    for e in cat.tiles.astype(np.int64):
+        rows = e[A_TILE] * bm + np.arange(bm)[:, None]
+        cols = e[B_TILE] * bn + np.arange(bn)[None, :]
+        window = ((rows >= e[R0]) & (rows < e[R1])
+                  & (cols >= e[C0]) & (cols < e[C1]))
+        lb_drops = (rows <= e[LB_R]) & (cols < e[LB_C])
+        ub_drops = (rows >= e[UB_R]) & (cols > e[UB_C])
+        out.append(bool(e[BAND] > 0)
+                   or bool((window & (lb_drops | ub_drops)).any()))
+    return np.asarray(out, bool)
+
+
+@given(raw_tiles())
+@settings(max_examples=200, deadline=None)
+def test_closed_form_tile_costs_match_row_sum_oracle(cat):
+    """The closed form, with its row-sum fallback, equals the (T, bm) row
+    sum on every tile, and the fallback takes exactly the tiles that a
+    band or a live corner cut reaches into."""
+    np.testing.assert_array_equal(tile_costs(cat),
+                                  lower_oracle.tile_costs(cat))
+    np.testing.assert_array_equal(row_sum_tiles(cat), _cuts_a_cell(cat))
 
 
 @given(any_catalog(), st.integers(1, 8),
